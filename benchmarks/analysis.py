@@ -1,9 +1,10 @@
 """Static-auditor lane for the benchmark orchestrator (BENCH_analysis.json).
 
 Not a timing benchmark: this module runs ``python -m repro.core.analysis``
-— the registry-wide static kernel auditor — as a child process (the CLI
-re-execs itself under forced host devices for the sharded cells, exactly
-like ``benchmarks/scaling.py``) and republishes its ``repro.analysis/v2``
+— the registry-wide static kernel auditor — as a child process with
+``JAX_PLATFORMS=cpu`` (it only traces, and the orchestrator process already
+holds any chip; the CLI re-execs itself under forced host devices for the
+sharded cells) and republishes its ``repro.analysis/v2``
 report as the orchestrator artifact.  The CSV row carries the audit
 wall-clock and the finding/waiver/skip counts as the derived column, so a
 drift in either shows up in the same place every other lane drifts.  Since
@@ -36,7 +37,7 @@ def run(smoke: bool = False, json_path: str = ARTIFACT) -> dict:
            "--json", os.path.abspath(json_path)]
     if smoke:
         cmd.append("--smoke")
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")   # tracing only: no chip
     src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

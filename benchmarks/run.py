@@ -37,6 +37,7 @@ import sys
 import traceback
 
 from benchmarks.common import header
+from repro.launch.compile_cache import configure_compile_cache
 
 MODULES = ["stencil", "babelstream", "minibude", "hartree_fock",
            "portability", "scaling", "roofline_kernels", "lm_step",
@@ -65,6 +66,7 @@ def main(argv=None) -> None:
         raise SystemExit(2)
     mods = [args.only] if args.only else MODULES
 
+    configure_compile_cache()
     header()
     failures = []
     for name in mods:

@@ -27,16 +27,19 @@ point.
 Hartree-Fock has no linear weak-scaling axis (work is O(N^4) in the atom
 count) and records a skip reason instead of a fake curve.
 
-Run on CPU via simulated devices, exactly how ``launch/dryrun.py`` fakes its
-512-chip topology: when the current process already pinned jax to a single
-device, the module re-execs itself in a subprocess with
-``--xla_force_host_platform_device_count`` appended to XLA_FLAGS
-(``repro.launch.hostsim`` — a user-set value is respected, never clobbered).
-The child's CSV rows are replayed into ``benchmarks.common.ROWS`` in the
-parent, so orchestrated runs (``benchmarks.run``) see them like any other
-module's.  CPU caveat: "devices" are threads of one host — and the
-``shard_pallas`` kernels run in interpret mode there — so efficiencies here
-validate the *machinery* and the shapes of the curves, not hardware scaling.
+On an accelerator the module measures in process on the devices it has and
+never starts a child: the process holds its chips, and a child that needs
+them would fail or hang.  On one chip that is the single-device point of
+each kernel, with the curves skipped for the stated reason.  Under
+``JAX_PLATFORMS=cpu`` with one visible device the module re-execs itself in
+a subprocess with ``--xla_force_host_platform_device_count`` appended to
+XLA_FLAGS (``repro.launch.hostsim`` — a user-set value is respected, never
+clobbered).  The child's CSV rows are replayed into
+``benchmarks.common.ROWS`` in the parent, so orchestrated runs
+(``benchmarks.run``) see them like any other module's.  CPU caveat:
+"devices" are threads of one host — and the ``shard_pallas`` kernels run in
+interpret mode there — so efficiencies here validate the *machinery* and the
+shapes of the curves, not hardware scaling.
 
     PYTHONPATH=src python -m benchmarks.run [--smoke] --only scaling
     PYTHONPATH=src python -m benchmarks.scaling [--smoke] [--devices 8]
@@ -50,6 +53,8 @@ decomposition curves and tuning provenance)::
      "num_devices": int,
      "kernels": [
        {"kernel": str, "baseline_backend": "xla",
+        "single_device": {"shape": str, "seconds": float,
+                          "tuning": TUNING},       # one-device runs only
         "backends": [
           {"backend": "xla_shard" | "shard_pallas", "skipped": str | null,
            "curves": [
@@ -246,10 +251,19 @@ def _measure(smoke: bool, json_path: str) -> Dict[str, Any]:
         if not any(kernel.backends.get(bk) is not None
                    and kernel.backends[bk].is_available()
                    for bk in backends):
+            # one device: its point is the whole measurement
+            strong_args = spec["make"](spec["strong"])
+            t1, t1_prov = _timed_point(kernel, strong_args, kernel.oracle,
+                                       cache, iters, warmup, {})
+            rec["single_device"] = {"shape": _shape_sig(strong_args),
+                                    "seconds": t1, "tuning": t1_prov}
+            emit(f"scaling.{name}.{kernel.oracle}.single_device", t1,
+                 f"devices={dc}")
             for bk in backends:
                 rec["backends"].append(
                     {"backend": bk, "curves": [],
-                     "skipped": f"{bk} unavailable ({dc} device(s))"})
+                     "skipped": f"{bk} unavailable ({dc} device(s)): a "
+                                f"scaling curve needs >= 2 devices"})
             continue
 
         # baselines are per kernel (shared by every backend's curves)
@@ -393,12 +407,13 @@ def _replay_child_line(line: str) -> None:
 
 def run(smoke: bool = False, json_path: str = ARTIFACT,
         devices: int = DEFAULT_DEVICES) -> Dict[str, Any]:
-    """Measure in-process when >= 2 devices are visible; otherwise re-exec
-    this module in a subprocess with the host-device-count flag appended
-    (jax reads XLA_FLAGS once, at backend init — too late for *this*
-    process).  Returns the artifact dict (also written to ``json_path``)."""
+    """Measure in-process on an accelerator or when >= 2 devices are
+    visible; otherwise (one CPU device) re-exec this module in a subprocess
+    with the host-device-count flag appended (jax reads XLA_FLAGS once, at
+    backend init — too late for *this* process).  Returns the artifact dict
+    (also written to ``json_path``)."""
     import jax
-    if jax.device_count() >= 2:
+    if jax.device_count() >= 2 or jax.devices()[0].platform != "cpu":
         return _measure(smoke=smoke, json_path=json_path)
     if os.environ.get("REPRO_SCALING_CHILD"):
         # we *are* the re-exec and still see one device: the user's own

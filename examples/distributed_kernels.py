@@ -46,6 +46,8 @@ import repro.kernels  # noqa: E402,F401  (registers xla_shard backends)
 from repro.core.portable import get_kernel  # noqa: E402
 from repro.kernels.hartree_fock import ref as hf_ref  # noqa: E402
 from repro.kernels.minibude import ops as mb_ops  # noqa: E402
+from repro.launch.compile_cache import (  # noqa: E402
+    configure_compile_cache)
 
 
 def show(name, kernel, args, exact=True, label=None, backend="xla_shard",
@@ -67,6 +69,7 @@ def show(name, kernel, args, exact=True, label=None, backend="xla_shard",
 
 
 def main() -> None:
+    configure_compile_cache()
     n = jax.device_count()
     if n < 2:
         raise SystemExit(

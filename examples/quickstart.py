@@ -17,6 +17,7 @@ import repro.kernels.babelstream.ops  # noqa: F401
 import repro.kernels.stencil7.ops  # noqa: F401
 from repro.core import Efficiency, get_kernel, phi_bar
 from repro.configs import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import transformer as T
 from repro.training.serve_step import generate
 from repro.training.train_step import TrainConfig, make_train_state, train_step
@@ -67,6 +68,7 @@ def lm_steps():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     science_kernels()
     lm_steps()
     print("\nquickstart OK")

@@ -16,9 +16,11 @@ import repro.kernels.hartree_fock.ops as hf_ops
 from repro.core import (Efficiency, babelstream_bytes, get_kernel,
                         minibude_ops, phi_bar, stencil7_effective_bytes)
 from repro.kernels.hartree_fock import ref as hf_ref
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main() -> None:
+    configure_compile_cache()
     rng = np.random.default_rng(0)
     terms = []
 

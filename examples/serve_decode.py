@@ -30,12 +30,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import transformer as T
 from repro.serving import ServingEngine, latency_summary, synthetic_trace
 from repro.training import serve_step as SS
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
     ap.add_argument("--requests", type=int, default=12)

@@ -24,6 +24,7 @@ from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.distributed.fault_tolerance import (Heartbeat, PreemptionGuard,
                                                StragglerMonitor)
 from repro.distributed.sharding import ShardingPolicy
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig
@@ -41,6 +42,7 @@ def scale_config(cfg, d_model=512, n_layers=8):
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
     ap.add_argument("--steps", type=int, default=300)

@@ -78,8 +78,8 @@ class ModelConfig:
     # --- serving attention dispatch ---
     # registry backend for self-attention ("xla" | "pallas" |
     # "pallas_interpret"); None = status-quo plain-XLA path.  The
-    # REPRO_ATTN_BACKEND env var overrides this at trace time, and
-    # unavailable Pallas backends fall back to XLA (see models/attention).
+    # REPRO_ATTN_BACKEND env var overrides this at trace time, and an
+    # unavailable requested backend raises (see models/attention).
     attn_backend: Optional[str] = None
 
     # ------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 The conformance suite (PR 5) is dynamic: it executes one case per cell and
 cannot see a write race in a Pallas grid, an accidental f64 promotion, an
-extra collective inside a ``check_rep=False`` shard_map body, or a Python
+extra collective inside a ``check_vma=False`` shard_map body, or a Python
 scalar baked into an ``lru_cache``'d compiled closure.  This package is the
 ahead-of-execution layer: every (kernel, backend) cell of the live registry
 is traced to a closed jaxpr on its conformance-case inputs —

@@ -5,10 +5,11 @@ Walks the derived (kernel, backend) matrix, runs the seven static passes
 writes a ``repro.analysis/v2`` JSON report, and exits nonzero iff any
 non-waived finding survives.  The sharded backends only *trace* on a
 multi-device topology, so when the parent process is pinned to one device
-the CLI re-execs itself under ``--xla_force_host_platform_device_count=8``
-(appended to — never clobbering — the user's XLA_FLAGS, exactly like
-``benchmarks/scaling.py``).  ``--smoke`` skips the re-exec and the
-per-tunable-point sweep: the seconds-scale drift-lane subset.
+the CLI re-execs itself under ``JAX_PLATFORMS=cpu`` and
+``--xla_force_host_platform_device_count=8`` (appended to — never
+clobbering — the user's XLA_FLAGS).  The audit only traces, so the child
+never needs a chip the parent may hold.  ``--smoke`` skips the re-exec and
+the per-tunable-point sweep: the seconds-scale drift-lane subset.
 """
 
 from __future__ import annotations
@@ -63,7 +64,7 @@ def _audit_here(args) -> int:
 
 def _reexec(args, devices: int) -> int:
     from repro.launch.hostsim import merged_xla_flags
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")   # tracing only: no chip
     env["XLA_FLAGS"] = merged_xla_flags(devices, env)
     env[_CHILD_ENV] = "1"
     src = os.path.dirname(os.path.dirname(os.path.dirname(

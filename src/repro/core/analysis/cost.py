@@ -221,13 +221,11 @@ def _pallas_traffic(gm: Any, mult: float, t: Traffic) -> float:
     if not enumerable:
         t.approx_grids += 1
     for bm in mappings:
-        try:
-            block = tuple(int(b) for b in bm.block_shape)
-            arr = bm.array_shape_dtype
-            shape = tuple(int(s) for s in arr.shape)
-            itemsize = int(np.dtype(arr.dtype).itemsize)
-        except (TypeError, ValueError, AttributeError):
-            continue  # non-Blocked/squeezed mapping: not modeled
+        block = JU.blocked_dims(bm)
+        if block is None:
+            continue  # element / bounded-slice indexing: not modeled
+        shape = tuple(int(s) for s in bm.array_aval.shape)
+        itemsize = int(np.dtype(bm.array_aval.dtype).itemsize)
         full_block = _prod(block) * itemsize
         arr_bytes = _prod(shape) * itemsize
         total = distinct = None

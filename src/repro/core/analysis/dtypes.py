@@ -3,7 +3,7 @@
 Two checks, both on traces of the conformance-case inputs:
 
   * **float64 promotion**: the kernel is re-traced under
-    ``jax.experimental.enable_x64()`` with its (float32) case inputs.  Any
+    ``jax.enable_x64(True)`` with its (float32) case inputs.  Any
     eqn producing a float64/complex128 value with no float64 input operand
     is a latent promotion — under the default x64-disabled config jax
     silently clamps it back to f32, but the same source run with x64
@@ -27,7 +27,7 @@ from typing import Any, List
 import jax
 import numpy as np
 
-from jax.core import Literal
+from jax.extend.core import Literal
 
 from repro.core.analysis import jaxpr_utils as JU
 from repro.core.analysis.report import Finding
@@ -45,8 +45,7 @@ def _dtype_of(var: Any):
 def run_f64_lint(kernel: str, backend: str, fn, args: tuple,
                  kwargs: dict) -> List[Finding]:
     """Re-trace under x64 and flag float64 eqns with no float64 operand."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = JU.trace(fn, args, kwargs)
     findings = []
     seen = set()

@@ -55,8 +55,9 @@ def audit_grid_mapping(kernel: str, backend: str, gm: Any,
         return findings
 
     for out_idx, bm in JU.output_block_mappings(gm):
-        mode = type(getattr(bm, "indexing_mode", None)).__name__
-        if mode not in ("Blocked", "NoneType"):
+        block = JU.blocked_dims(bm)
+        if block is None:
+            mode = sorted({type(d).__name__ for d in bm.block_shape})
             findings.append(Finding(
                 kernel=kernel, backend=backend, pass_name="grid",
                 code="unaudited-indexing-mode", severity="warning",
@@ -64,8 +65,7 @@ def audit_grid_mapping(kernel: str, backend: str, gm: Any,
                          f"{mode}; coverage proof only models Blocked"),
                 detail={"output": out_idx, "mode": mode}))
             continue
-        block = tuple(int(b) for b in bm.block_shape)
-        arr_shape = tuple(int(s) for s in bm.array_shape_dtype.shape)
+        arr_shape = tuple(int(s) for s in bm.array_aval.shape)
         nblocks = tuple(-(-s // b) for s, b in zip(arr_shape, block))
 
         visits: dict = {}

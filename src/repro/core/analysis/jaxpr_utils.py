@@ -16,11 +16,11 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
-from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
-#: shard_map spells psum as ``psum2`` since jax 0.4.31; both count as psum.
-#: ``pbroadcast`` is replication bookkeeping, not data movement — ignored.
-PSUM_PRIMITIVES = ("psum", "psum2")
+#: ``jax.shard_map`` spells psum ``psum_invariant`` under ``check_vma=True``;
+#: both count as psum.
+PSUM_PRIMITIVES = ("psum", "psum_invariant")
 COLLECTIVE_KINDS = ("ppermute", "psum", "all_gather")
 
 
@@ -47,7 +47,7 @@ def trace(fn: Callable[..., Any], args: tuple, kwargs: dict) -> ClosedJaxpr:
 
 
 def count_collectives(jaxpr: Jaxpr) -> Dict[str, int]:
-    """Collective-primitive census: ppermute / psum(+psum2) / all_gather."""
+    """Collective-primitive census: ppermute / psum / all_gather."""
     counts = {k: 0 for k in COLLECTIVE_KINDS}
     for eqn in iter_eqns(jaxpr):
         name = eqn.primitive.name
@@ -106,6 +106,23 @@ def eval_index_map(index_map_jaxpr: ClosedJaxpr,
     out = jax.core.eval_jaxpr(index_map_jaxpr.jaxpr, index_map_jaxpr.consts,
                               *idx)
     return tuple(int(v) for v in out)
+
+
+def blocked_dims(bm: Any) -> Optional[Tuple[int, ...]]:
+    """Block extents of a BlockMapping whose every dim is ``Blocked`` or
+    ``Squeezed`` (extent 1); ``None`` when a dim uses element or
+    bounded-slice indexing, which the coverage and traffic models do not
+    cover."""
+    dims = []
+    for d in bm.block_shape:
+        kind = type(d).__name__
+        if kind == "Blocked":
+            dims.append(int(d.block_size))
+        elif kind == "Squeezed":
+            dims.append(1)
+        else:
+            return None
+    return tuple(dims)
 
 
 def output_block_mappings(grid_mapping: Any) -> List[Tuple[int, Any]]:

@@ -12,27 +12,14 @@ from __future__ import annotations
 import dataclasses
 import re
 from collections import defaultdict
-from typing import Dict, Iterable, Mapping, Optional, Tuple
+from typing import Dict, Iterable, Optional, Tuple
 
 __all__ = [
     "CollectiveStats",
     "parse_collective_bytes",
     "dtype_bytes",
     "parse_shape_bytes",
-    "xla_cost_analysis",
 ]
-
-
-def xla_cost_analysis(compiled) -> Mapping[str, float]:
-    """`compiled.cost_analysis()` as a flat dict on every jax version.
-
-    Older jax returns a one-element list of per-device dicts; newer jax
-    returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 # XLA HLO collective op mnemonics we account for.
 _COLLECTIVE_KINDS = (

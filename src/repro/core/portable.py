@@ -410,10 +410,8 @@ class PortableKernel:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    # a backend that fails to initialize raises here: it is not "not a TPU"
+    return jax.devices()[0].platform == "tpu"
 
 
 #: availability predicate for compiled pallas-TPU backends (public so

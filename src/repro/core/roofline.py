@@ -21,7 +21,7 @@ from repro.core.hlo_analysis import CollectiveStats, parse_collective_bytes
 from repro.core.hlo_cost import HloCost, analyze_hlo
 
 __all__ = ["ChipSpec", "TPU_V5E", "NVIDIA_H100", "AMD_MI300A", "CPU_HOST",
-           "CHIP_SPECS", "detect_chip", "RooflineTerms",
+           "CHIP_SPECS", "TPU_SPECS", "detect_chip", "RooflineTerms",
            "roofline_from_compiled", "model_flops"]
 
 
@@ -81,28 +81,34 @@ CHIP_SPECS: Dict[str, ChipSpec] = {
     c.name: c for c in (TPU_V5E, NVIDIA_H100, AMD_MI300A, CPU_HOST)
 }
 
+#: TPU peaks keyed by the ``device_kind`` JAX reports; a TPU kind missing
+#: here is an error, never another chip's peaks
+TPU_SPECS: Dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
+
 
 def detect_chip(platform: Optional[str] = None,
                 device_kind: Optional[str] = None) -> ChipSpec:
     """Map the local jax backend (or explicit platform/device_kind strings)
     to the ChipSpec whose peaks the roofline verdict should name.
 
-    TPU hosts get the assignment's v5e spec, GPU hosts are split H100 vs
-    MI300A on the device-kind string, and everything else (the CPU CI
-    lane, forced host devices) falls back to ``CPU_HOST``.
+    TPUs are looked up by ``device_kind`` in ``TPU_SPECS`` (an unknown kind
+    raises ``ValueError``), GPU hosts are split H100 vs MI300A on the
+    device-kind string, and everything else (the CPU CI lane, forced host
+    devices) maps to ``CPU_HOST``.  A backend that fails to initialize
+    raises; it is not read as a CPU.
     """
     if platform is None:
-        try:
-            import jax
-            dev = jax.devices()[0]
-            platform = dev.platform
-            device_kind = getattr(dev, "device_kind", "") or ""
-        except Exception:
-            return CPU_HOST
-    platform = (platform or "").lower()
-    kind = (device_kind or "").lower()
+        import jax
+        dev = jax.devices()[0]
+        platform, device_kind = dev.platform, dev.device_kind
+    platform = platform.lower()
     if platform == "tpu":
-        return TPU_V5E
+        if device_kind not in TPU_SPECS:
+            raise ValueError(
+                f"no peak table for TPU device_kind {device_kind!r}; "
+                f"known kinds: {sorted(TPU_SPECS)}")
+        return TPU_SPECS[device_kind]
+    kind = (device_kind or "").lower()
     if platform in ("gpu", "cuda", "rocm"):
         if "mi300" in kind or "amd" in kind or platform == "rocm":
             return AMD_MI300A
@@ -167,8 +173,7 @@ def roofline_from_compiled(compiled: Any, chip: ChipSpec = TPU_V5E,
     hc = analyze_hlo(text, vmem_scopes=KERNEL_VMEM_SCOPES
                      if kernel_adjusted else ())
 
-    from repro.core.hlo_analysis import xla_cost_analysis
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
 

@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -354,7 +353,8 @@ def _stencil_sharded(sz, sy, overlap, invhx2, invhy2, invhz2, invhxyz2):
         mesh, spec = shard_mesh2d(sz, sy), P(AXIS_Z, AXIS_Y)
         local = functools.partial(_pencil_local, sz=sz, sy=sy, coeffs=coeffs,
                                   overlap=overlap)
-    return jax.jit(shard_map(local, mesh, in_specs=spec, out_specs=spec))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False))
 
 
 def laplacian_shard(u, invhx2=1.0, invhy2=1.0, invhz2=1.0, invhxyz2=-6.0,
@@ -410,8 +410,8 @@ def _stream_sharded(op, num_shards):
         in_specs = (P(AXIS),) * nargs + (P(),)
     else:
         local, in_specs = body, (P(AXIS),) * nargs
-    return jax.jit(shard_map(local, mesh, in_specs=in_specs,
-                             out_specs=out_spec))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_spec, check_vma=False))
 
 
 def _make_stream_shard(op, nargs, takes_scalar):
@@ -448,10 +448,10 @@ def _fasten_sharded(num_shards):
     mesh = shard_mesh(num_shards)
     # decks replicate, poses (6, P) shard along P; per-pose energies are
     # independent, so out_specs concatenation reassembles the exact result
-    return jax.jit(shard_map(
-        mb_ref.fasten, mesh,
+    return jax.jit(jax.shard_map(
+        mb_ref.fasten, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(None, AXIS)),
-        out_specs=P(AXIS)))
+        out_specs=P(AXIS), check_vma=False))
 
 
 def fasten_shard(protein_pos, protein_par, ligand_pos, ligand_par, poses,
@@ -509,8 +509,8 @@ def _fock_sharded(num_shards, ngauss):
         k_term = jnp.einsum("ikjl,kl->ij", eri, d_slab)
         return lax.psum(j_term - k_term, AXIS)
 
-    return jax.jit(shard_map(local, mesh, in_specs=(P(), P()),
-                             out_specs=P()))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=P(), check_vma=False))
 
 
 def fock_shard(positions, density, *, ngauss: int = 3,

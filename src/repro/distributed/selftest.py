@@ -107,7 +107,6 @@ def _check_stencil_one_plane_per_shard(np, jnp, get_kernel, n_devices):
 
 def _check_halo_exchange(np, jnp, n_shards):
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import collectives
@@ -120,9 +119,9 @@ def _check_halo_exchange(np, jnp, n_shards):
         lo, hi = collectives.halo_exchange(u, AXIS, n_shards, axis=0)
         return jnp.concatenate([lo, hi], axis=0)
 
-    halos = np.asarray(jax.jit(shard_map(
-        local, shard_mesh(n_shards), in_specs=P(AXIS),
-        out_specs=P(AXIS)))(x))
+    halos = np.asarray(jax.jit(jax.shard_map(
+        local, mesh=shard_mesh(n_shards), in_specs=P(AXIS),
+        out_specs=P(AXIS), check_vma=False))(x))
     xs = np.asarray(x).reshape(n_shards, 2, 3)
     halos = halos.reshape(n_shards, 2, 3)
     for i in range(n_shards):
@@ -137,7 +136,6 @@ def _check_halo_exchange(np, jnp, n_shards):
 def _check_halo_wrap_and_multiplane(np, jnp, n_shards):
     """The wrap=True periodic ring and halo>1 multi-plane slabs."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import collectives
@@ -149,9 +147,9 @@ def _check_halo_wrap_and_multiplane(np, jnp, n_shards):
     xs = np.asarray(x).reshape(n_shards, planes, 2)
 
     def run(local):
-        return np.asarray(jax.jit(shard_map(
-            local, shard_mesh(n_shards), in_specs=P(AXIS),
-            out_specs=P(AXIS)))(x))
+        return np.asarray(jax.jit(jax.shard_map(
+            local, mesh=shard_mesh(n_shards), in_specs=P(AXIS),
+            out_specs=P(AXIS), check_vma=False))(x))
 
     # periodic shift: every shard receives its predecessor's block, the
     # first shard wrapping around to the last

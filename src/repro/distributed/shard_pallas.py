@@ -31,7 +31,7 @@ tile tunables (``by`` / ``block_rows`` / ``pose_tile`` / ``i_tile``) in one
     partial Fock matrices accumulate with ``psum``.
 
 ``shard_map`` has no replication rule for ``pallas_call``, so every wrapper
-here passes ``check_rep=False``.  Off-TPU the kernels run in
+here passes ``check_vma=False``.  Off-TPU the kernels run in
 ``interpret=True`` mode — the same validation path the single-device
 ``pallas_interpret`` backends use — so the whole composition is exercisable
 on forced host devices (``repro.launch.hostsim``); on TPU the compiled
@@ -52,7 +52,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.portable import get_kernel, on_tpu
@@ -174,8 +173,8 @@ def _stencil_shard_pallas(sz, sy, by, interpret, invhx2, invhy2, invhz2,
         mesh, spec = shard_mesh2d(sz, sy), P(AXIS_Z, AXIS_Y)
         local = functools.partial(_pencil_local_pallas, sz=sz, sy=sy,
                                   coeffs=coeffs, by=by, interpret=interpret)
-    return jax.jit(shard_map(local, mesh, in_specs=spec, out_specs=spec,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False))
 
 
 def laplacian_shard_pallas(u, invhx2=1.0, invhy2=1.0, invhz2=1.0,
@@ -229,8 +228,9 @@ def _stream_shard_pallas(op, num_shards, block_rows, interpret, scalar):
                            interpret=interpret)
             return out.reshape(-1)
         out_spec = P(AXIS)
-    return jax.jit(shard_map(local, mesh, in_specs=(P(AXIS),) * nargs,
-                             out_specs=out_spec, check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(AXIS),) * nargs,
+                                 out_specs=out_spec, check_vma=False))
 
 
 def _make_stream_shard_pallas(op, nargs, takes_scalar):
@@ -286,9 +286,9 @@ def _fasten_shard_pallas(num_shards, pose_tile, interpret):
 
     # decks replicate, poses (6, P) shard along P; fasten_tiled returns a
     # (1, P_local) row whose concatenation along lanes is the exact result
-    return jax.jit(shard_map(
-        local, mesh, in_specs=(P(), P(), P(), P(), P(None, AXIS)),
-        out_specs=P(None, AXIS), check_rep=False))
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P(), P(), P(), P(None, AXIS)),
+        out_specs=P(None, AXIS), check_vma=False))
 
 
 def fasten_shard_pallas(protein_pos, protein_par, ligand_pos, ligand_par,
@@ -319,8 +319,8 @@ def _fock_shard_pallas(num_shards, ngauss, i_tile, interpret):
                                      i_tile=i_tile, interpret=interpret)
         return lax.psum(part, AXIS)
 
-    return jax.jit(shard_map(local, mesh, in_specs=(P(), P()),
-                             out_specs=P(), check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=P(), check_vma=False))
 
 
 def fock_shard_pallas(positions, density, *, ngauss: int = 3,

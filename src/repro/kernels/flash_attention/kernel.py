@@ -190,7 +190,7 @@ def _decode_body(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, m_scr, l_scr,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale  # (G, bkv)
 
-    qp = qp_ref[0, 0]                                # scalar int32
+    qp = qp_ref[pl.program_id(0)]                    # scalar int32 (SMEM)
     kp = kp_ref[0]                                   # (1, bkv) int32
     mask = (kp >= 0) & (kp <= qp)                    # empty slots + causal
     if window:
@@ -239,7 +239,9 @@ def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
             pl.BlockSpec((1, 1, g, dh), lambda b_, k_, ti: (b_, k_, 0, 0)),
             pl.BlockSpec((1, 1, bkv, dh), lambda b_, k_, ti: (b_, k_, ti, 0)),
             pl.BlockSpec((1, 1, bkv, dh), lambda b_, k_, ti: (b_, k_, ti, 0)),
-            pl.BlockSpec((1, 1), lambda b_, k_, ti: (b_, 0)),
+            # every row's query position, whole in SMEM: a (1, 1) block of
+            # a (B, 1) array breaks the TPU (8, 128) tiling rule for B > 1
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, bkv), lambda b_, k_, ti: (b_, 0, ti)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dh),
@@ -251,4 +253,4 @@ def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
             pltpu.VMEM((g, dh), jnp.float32),    # running accumulator
         ],
         interpret=interpret,
-    )(q, k, v, q_pos, k_pos)
+    )(q, k, v, q_pos.reshape(b), k_pos)

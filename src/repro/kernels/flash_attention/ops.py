@@ -11,11 +11,10 @@ Registers two kernel families the serving hot path dispatches through
     the ``xla`` oracle is literally the plain-XLA ``attend`` path serving
     has always run (bitwise, no layout moves).
 
-Availability follows the ``shard_pallas`` convention: the compiled
-``pallas`` backend declares ``available=on_tpu`` but its wrapper defaults
-``interpret=None`` -> interpret everywhere but TPU, so a direct call (or a
-dispatch that slipped past the availability check) degrades to the
-interpret path off-TPU instead of crashing.
+The compiled ``pallas`` backend declares ``available=on_tpu`` and always
+compiles (``interpret=False``); interpret mode is reached only through the
+``pallas_interpret`` backend, so a Pallas timing on the chip is never an
+interpreter timing.
 """
 
 from __future__ import annotations
@@ -30,21 +29,10 @@ from repro.kernels.flash_attention import kernel as K
 from repro.kernels.flash_attention.ref import decode_ref, flash_ref
 
 
-def _interpret_capable() -> bool:
-    """Pallas interpret mode needs any jax backend at all."""
-    try:
-        jax.devices()
-        return True
-    except Exception:  # pragma: no cover - no jax backend at all
-        return False
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
 def flash_pallas(q, k, v, q_pos=None, k_pos=None, *, causal=True, window=0,
-                 bq=K.DEFAULT_BQ, bk=K.DEFAULT_BK, interpret=None):
-    if interpret is None:          # off-TPU fallback, never a crash
-        interpret = not on_tpu()
+                 bq=K.DEFAULT_BQ, bk=K.DEFAULT_BK, interpret=False):
     if q_pos is not None:
         b, h, s, _ = q.shape
         t = k.shape[2]
@@ -60,9 +48,7 @@ flash_xla = jax.jit(flash_ref, static_argnames=("causal", "window"))
 
 @functools.partial(jax.jit, static_argnames=("window", "bkv", "interpret"))
 def decode_pallas(q, k, v, q_pos, k_pos, *, window=0, bkv=K.DEFAULT_BKV,
-                  interpret=None):
-    if interpret is None:          # off-TPU fallback, never a crash
-        interpret = not on_tpu()
+                  interpret=False):
     b, s, h, dh = q.shape          # s == 1 (single decode query)
     kv = k.shape[2]
     g = h // kv
@@ -97,8 +83,7 @@ _k = register_kernel("attention.flash", flops_model=_flops_model,
 _k.add_backend("xla", flash_xla)
 _k.add_backend("pallas", flash_pallas, available=on_tpu)
 _k.add_backend("pallas_interpret",
-               functools.partial(flash_pallas, interpret=True),
-               available=_interpret_capable)
+               functools.partial(flash_pallas, interpret=True))
 # q/k block sizes of the online-softmax loop — must divide S and T
 _k.declare_tunables(
     ("pallas", "pallas_interpret"),
@@ -118,8 +103,7 @@ _kd = register_kernel("attention.decode", flops_model=_decode_flops_model,
 _kd.add_backend("xla", decode_xla)
 _kd.add_backend("pallas", decode_pallas, available=on_tpu)
 _kd.add_backend("pallas_interpret",
-                functools.partial(decode_pallas, interpret=True),
-                available=_interpret_capable)
+                functools.partial(decode_pallas, interpret=True))
 # cache-axis block size of the online-softmax loop — must divide cache_len
 _kd.declare_tunables(
     ("pallas", "pallas_interpret"),

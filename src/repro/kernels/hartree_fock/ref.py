@@ -66,10 +66,11 @@ def sto_basis(ngauss: int = 3, dtype=jnp.float32) -> Basis:
                  coefficients=jnp.asarray(coef * norm, dtype))
 
 
-def boys_f0(t: jnp.ndarray) -> jnp.ndarray:
-    """F0 Boys function, series-guarded at t -> 0."""
+def boys_f0(t: jnp.ndarray, erf=jax.lax.erf) -> jnp.ndarray:
+    """F0 Boys function, series-guarded at t -> 0 (``erf`` is swappable for
+    kernels whose lowering lacks ``lax.erf``)."""
     t_safe = jnp.maximum(t, 1e-12)
-    big = 0.5 * jnp.sqrt(jnp.pi / t_safe) * jax.lax.erf(jnp.sqrt(t_safe))
+    big = 0.5 * jnp.sqrt(jnp.pi / t_safe) * erf(jnp.sqrt(t_safe))
     small = 1.0 - t / 3.0 + t * t / 10.0
     return jnp.where(t < 1e-6, small, big)
 

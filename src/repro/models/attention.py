@@ -9,9 +9,9 @@ that routes prefill-shaped calls to the ``attention.flash`` portable kernel
 and single-query decode calls to ``attention.decode`` (see
 ``kernels/flash_attention/ops.py``), with backend selection via the
 ``REPRO_ATTN_BACKEND`` env var or an explicit ``backend=`` argument
-(``ModelConfig.attn_backend`` threads it here), availability fallback past
-unavailable Pallas backends, and tuned block sizes injected from the
-persistent tuning cache.  The plain-XLA math lives in ``attend_xla`` — the
+(``ModelConfig.attn_backend`` threads it here; a requested backend that
+cannot run here raises ``BackendUnavailableError``), and tuned block sizes
+injected from the persistent tuning cache.  The plain-XLA math lives in ``attend_xla`` — the
 registry oracle for both kernel entries, and the path every call takes when
 no backend is requested, so training and default serving are bitwise
 unchanged.
@@ -204,8 +204,7 @@ def _get_kernel(kind: str):
     only be consulted after both are loaded)."""
     from repro.core.portable import registry
     import repro.kernels  # noqa: F401  (side effect: registers kernels)
-    name = ATTN_KERNELS[kind]
-    return registry.get(name) if name in registry else None
+    return registry.get(ATTN_KERNELS[kind])
 
 
 def resolve_attention_backend(kind: str,
@@ -215,8 +214,9 @@ def resolve_attention_backend(kind: str,
     Precedence: ``REPRO_ATTN_BACKEND`` env var > explicit ``backend``
     argument > ``"xla"`` (the status-quo plain-XLA path).  A requested
     backend that exists but is unavailable on this host (e.g. ``"pallas"``
-    off-TPU) falls back to ``"xla"`` rather than crashing; an *unknown*
-    name raises so config typos surface immediately.
+    off-TPU) raises ``BackendUnavailableError`` with the probe's reason —
+    it never silently becomes ``"xla"``; an *unknown* name raises
+    ``KeyError`` so config typos surface immediately.
     """
     if kind not in ATTN_KERNELS:
         raise KeyError(f"unknown attention dispatch kind {kind!r}; "
@@ -225,16 +225,12 @@ def resolve_attention_backend(kind: str,
     if req is None or req == "xla":
         return "xla"
     kernel = _get_kernel(kind)
-    if kernel is None:
-        return "xla"
-    b = kernel.backends.get(req)
-    if b is None:
+    if req not in kernel.backends:
         raise KeyError(
             f"unknown attention backend {req!r} for {ATTN_KERNELS[kind]!r}; "
             f"have {sorted(kernel.backends)}")
-    if b.is_available():
-        return req
-    return "xla"
+    kernel._require_available(req)      # BackendUnavailableError if not
+    return req
 
 
 def _tuned_params(kernel, *args, backend: str, **kwargs):

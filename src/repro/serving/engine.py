@@ -140,8 +140,9 @@ class ServingEngine:
             raise ValueError(f"unknown cache_layout {cache_layout!r}")
         self.params = params
         self.cfg = cfg
-        # what the compiled programs will actually dispatch to (env var /
-        # availability fallback applied) — benchmark rows report this
+        # what the compiled programs will actually dispatch to (env var
+        # applied; an unavailable backend raises here) — benchmark rows
+        # report this
         self.attn_backends = {
             kind: resolve_attention_backend(kind, cfg.attn_backend)
             for kind in ("prefill", "decode")}

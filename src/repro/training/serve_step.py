@@ -74,6 +74,12 @@ def sample_per_slot(logits: jnp.ndarray, keys: jnp.ndarray,
     )(logits, keys)
 
 
+_prefill_jit = jax.jit(prefill, static_argnums=(1,),
+                       static_argnames=("cache_len", "hints", "attn_backend"))
+_decode_step_jit = jax.jit(decode_step, static_argnums=(1,),
+                           static_argnames=("hints", "attn_backend"))
+
+
 def generate(params, cfg: ModelConfig, prompt: jnp.ndarray, *,
              max_new_tokens: int, cache_len: int, key=None,
              temperature: float = 0.0, frames=None, patches=None,
@@ -82,18 +88,18 @@ def generate(params, cfg: ModelConfig, prompt: jnp.ndarray, *,
     if key is None:
         key = jax.random.PRNGKey(0)
     b, s = prompt.shape
-    last, caches, memory = prefill(params, cfg, prompt, cache_len=cache_len,
-                                   frames=frames, patches=patches,
-                                   hints=hints, attn_backend=attn_backend)
+    last, caches, memory = _prefill_jit(
+        params, cfg, prompt, cache_len=cache_len, frames=frames,
+        patches=patches, hints=hints, attn_backend=attn_backend)
     out = []
     tok = sample(last, key, temperature)
     out.append(tok)
     for i in range(1, max_new_tokens):
         key, sub = jax.random.split(key)
         pos = jnp.full((b, 1), s + i - 1, jnp.int32)
-        logits, caches = decode_step(params, cfg, tok[:, None], pos, caches,
-                                     memory=memory, hints=hints,
-                                     attn_backend=attn_backend)
+        logits, caches = _decode_step_jit(params, cfg, tok[:, None], pos,
+                                          caches, memory=memory, hints=hints,
+                                          attn_backend=attn_backend)
         tok = sample(logits, sub, temperature)
         out.append(tok)
     return jnp.stack(out, axis=1)

@@ -1,6 +1,6 @@
 """PR-6 attention registry dispatch: decode kernel vs ``attend``, backend
-resolution (env var / availability fallback), tuned-param injection, and the
-per-backend batched↔unbatched serving bit-match.
+resolution (env var precedence, unavailable-backend raise), tuned-param
+injection, and the per-backend batched↔unbatched serving bit-match.
 
 The decode oracle contract is *bitwise*: ``attention.decode``'s xla backend
 is literally the plain-XLA ``attend_xla`` path serving has always run (both
@@ -19,7 +19,7 @@ import pytest
 import repro.kernels  # noqa: F401  (registers attention.* backends)
 from repro.configs import get_config
 from repro.core import conformance, tuning
-from repro.core.portable import registry
+from repro.core.portable import BackendUnavailableError, registry
 from repro.models import attention as A
 from repro.models import transformer as T
 from repro.serving import Request, ServingEngine
@@ -163,8 +163,9 @@ def test_resolve_precedence_and_fallback(monkeypatch):
         assert A.resolve_attention_backend(
             "decode", "pallas_interpret") == "pallas_interpret"
     if not ik.backends["pallas"].is_available():
-        # requested-but-unavailable falls back past pallas to the oracle
-        assert A.resolve_attention_backend("decode", "pallas") == "xla"
+        # requested-but-unavailable raises; it never becomes the oracle
+        with pytest.raises(BackendUnavailableError, match="pallas"):
+            A.resolve_attention_backend("decode", "pallas")
     # env var wins over the argument
     monkeypatch.setenv(A.ATTN_BACKEND_ENV, "xla")
     assert A.resolve_attention_backend("decode", "pallas_interpret") == "xla"
@@ -173,6 +174,19 @@ def test_resolve_precedence_and_fallback(monkeypatch):
         A.resolve_attention_backend("decode", "no_such_backend")
     with pytest.raises(KeyError):
         A.resolve_attention_backend("no_such_kind", "xla")
+
+
+@pytest.mark.parametrize("name", ["attention.flash", "attention.decode"])
+def test_compiled_pallas_never_interprets(name):
+    """``pallas`` means the compiled kernel: off-TPU a direct call fails in
+    the compiler instead of quietly running the interpreter."""
+    q, kc, vc, qp, kp = _decode_inputs(13, t=64)
+    if name == "attention.flash":
+        q, kc, vc = (jnp.moveaxis(x, 2, 1) for x in (q, kc, vc))
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("compiled Pallas runs here")
+    with pytest.raises(ValueError, match="interpret"):
+        registry.get(name)(q, kc, vc, qp, kp, backend="pallas")
 
 
 def test_attend_dispatch_routes_and_falls_back(monkeypatch):

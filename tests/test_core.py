@@ -7,8 +7,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core.hlo_analysis import (parse_collective_bytes,
-                                     parse_shape_bytes, xla_cost_analysis)
+from repro.core.hlo_analysis import parse_collective_bytes, parse_shape_bytes
 from repro.core.hlo_cost import analyze_hlo
 from repro.core.metrics import Efficiency, phi_bar
 from repro.core.portable import PortableKernel
@@ -106,5 +105,5 @@ def test_hlo_cost_matches_xla_on_flat_program():
     b = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     c = jax.jit(f).lower(a, b).compile()
     got = analyze_hlo(c.as_text())
-    xla = xla_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(got.flops - xla) / xla < 0.2

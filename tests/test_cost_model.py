@@ -81,16 +81,15 @@ def test_census_pallas_counts_halo_rereads():
 
 def test_census_collective_bytes():
     """psum under shard_map counts its payload, scaled by the mesh size."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     ndev = jax.device_count()
     mesh = Mesh(np.array(jax.devices()), ("x",))
 
     def summed(a):
-        return shard_map(
+        return jax.shard_map(
             lambda blk: jax.lax.psum(jnp.sum(blk), "x"),
-            mesh=mesh, in_specs=P("x"), out_specs=P())(a)
+            mesh=mesh, in_specs=P("x"), out_specs=P(), check_vma=False)(a)
 
     x = jax.ShapeDtypeStruct((8 * ndev,), jnp.float32)
     t = cost.census(_trace(summed, x))
@@ -127,7 +126,11 @@ def test_verdict_collective_bound_and_shards():
 
 
 def test_detect_chip_mapping():
-    assert detect_chip("tpu") is TPU_V5E
+    assert detect_chip("tpu", "TPU v5 lite") is TPU_V5E
+    # an unknown TPU kind raises instead of borrowing v5e peaks
+    for kind in ("TPU v4", "TPU v6 lite", None):
+        with pytest.raises(ValueError, match="no peak table"):
+            detect_chip("tpu", kind)
     assert detect_chip("gpu") is NVIDIA_H100
     assert detect_chip("cuda", "NVIDIA H100 80GB HBM3") is NVIDIA_H100
     assert detect_chip("gpu", "AMD Instinct MI300A") is AMD_MI300A

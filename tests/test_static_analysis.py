@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -224,15 +223,15 @@ def test_planted_accum_downgrade_fires():
 
 def test_planted_undeclared_all_gather_fires():
     """A sharded body that quietly re-materializes the global array.
-    check_rep=False mirrors how such a defect ships: replication checking
+    check_vma=False mirrors how such a defect ships: replication checking
     would have rejected the spec combination outright."""
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
     def gathers(x):
         def body(lx):
             return jnp.sum(jax.lax.all_gather(lx, "x"))
-        return shard_map(body, mesh, in_specs=(P("x"),), out_specs=P(),
-                        check_rep=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P("x"),),
+                             out_specs=P(), check_vma=False)(x)
 
     closed = JU.trace(gathers, (jnp.ones((8,), jnp.float32),), {})
     (_, expected), = collectives_audit.normalize_contract(None, ())
@@ -247,16 +246,16 @@ def test_comm_contract_mismatch_fires():
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
     def summed(x):
-        return shard_map(lambda lx: jax.lax.psum(lx, "x"), mesh,
-                         in_specs=(P("x"),), out_specs=P())(x)
+        return jax.shard_map(lambda lx: jax.lax.psum(lx, "x"), mesh=mesh,
+                             in_specs=(P("x"),), out_specs=P(),
+                             check_vma=False)(x)
 
     closed = JU.trace(summed, (jnp.ones((8,), jnp.float32),), {})
     findings = collectives_audit.check_counts(
         "planted", "xla_shard", closed, {"ppermute": 0, "psum": 0},
         declared=True)
     assert {f.code for f in findings} == {"comm-contract-mismatch"}
-    # and the correct declaration audits clean (psum spelled psum2 inside
-    # shard_map — the census must see through the renaming)
+    # and the correct declaration audits clean
     assert collectives_audit.check_counts(
         "planted", "xla_shard", closed, {"ppermute": 0, "psum": 1},
         declared=True) == []
