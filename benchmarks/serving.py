@@ -27,11 +27,10 @@ A row whose trace does not fully drain FAILS the benchmark (RuntimeError):
 half-served traces cannot masquerade as clean SLO percentiles.
 
 Since PR 8 the whole run records through ``repro.core.telemetry``: request
-lifecycles, queue-depth/slot-occupancy gauges, attention dispatch events,
-and — via the ``jax.monitoring`` bridge — an XLA compile-event counter per
-row.  The trace is exported next to the artifact as
-``BENCH_serving_trace.jsonl`` (feed to ``python -m repro.core.telemetry
-summarize``) and a Chrome/Perfetto-loadable ``BENCH_serving_trace.json``.
+lifecycles, the engine's spans, attention dispatch events, and — via the
+``jax.monitoring`` bridge — an XLA compile-event counter per row.  The
+trace is exported next to the artifact as ``BENCH_serving_trace.jsonl``
+(feed to ``python -m repro.core.telemetry summarize``).
 
 A machine-readable artifact is written to ``BENCH_serving.json`` (schema
 ``repro.serving/v4``; v3 had a single rate and a single cache layout and no
@@ -268,14 +267,12 @@ def run(smoke: bool = False, json_path: str = ARTIFACT) -> Dict[str, Any]:
         events.extend(rec.drain() if rec is not None else [])
         stem = json_path[:-5] if json_path.endswith(".json") else json_path
         trace_jsonl = f"{stem}_trace.jsonl"
-        trace_chrome = f"{stem}_trace.json"
         meta = {"benchmark": "serving", "arch": ARCH, "schema_of": SCHEMA}
         if rec is not None:
-            snap = rec.snapshot()     # counters/gauges survive the drains
+            snap = rec.snapshot()     # counters survive the drains
             snap["span_summary"] = tel.summarize_events(events)
             tel.write_jsonl(trace_jsonl, events, meta=meta,
                             footer_data=snap)
-            tel.write_chrome_trace(trace_chrome, events, meta=meta)
         else:  # pragma: no cover - recorder always on here
             snap = {}
 
@@ -294,7 +291,6 @@ def run(smoke: bool = False, json_path: str = ARTIFACT) -> Dict[str, Any]:
                 COMPILE_COUNTER, 0.0),
             "telemetry": snap,
             "trace_jsonl": trace_jsonl,
-            "trace_chrome": trace_chrome,
             "engines": engines,
             "rows": rows,
         }

@@ -1,14 +1,24 @@
 """Registry-wide runtime telemetry (schema ``repro.telemetry/v1``).
 
 One shared substrate for every measured number in the repo: structured
-spans (monotonic start/duration, parent nesting), counters, and gauges in a
-thread-safe bounded ring buffer, with three exporters (JSONL event log,
-Chrome/Perfetto ``trace.json``, flat metrics snapshot for ``BENCH_*.json``
-artifacts) and a CLI::
+spans (monotonic start/duration, parent nesting) and counters, with two
+sinks for spans:
 
-    python -m repro.core.telemetry summarize <trace>   # count/total/p50/p95/p99
+  * the in-memory ring (``REPRO_TELEMETRY``): a thread-safe bounded buffer
+    exported as a JSONL event log or a flat metrics snapshot for
+    ``BENCH_*.json`` artifacts, and summarized by a CLI::
 
-Control is environmental and zero-cost when off::
+        python -m repro.core.telemetry summarize <trace>  # p50/p95/p99
+
+  * the JAX profiler's trace: while a profiler session collects
+    (``jax.profiler.start_trace``), every ``span`` also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, with its attributes
+    as event stats.  The span then lands on the ``/host:CPU`` plane of the
+    ``.xplane.pb``, on the device planes' clock, whatever
+    ``REPRO_TELEMETRY`` says.  A ``gc.callbacks`` hook likewise brackets
+    each garbage collection in a ``python.gc`` span (stat ``generation``).
+
+The ring is controlled from the environment::
 
     REPRO_TELEMETRY=off          # default: module-level no-op fast path
     REPRO_TELEMETRY=on           # record into the in-memory ring
@@ -21,16 +31,17 @@ Instrumentation sites call the module-level helpers::
     with tel.span("serving.decode_step", proc="engine", active=n):
         ...                       # around the jit call, never inside it
     tel.counter("tuning.cache.hit")
-    tel.gauge("serving.queue_depth", len(queue), proc="engine")
 
-When disabled (the default) ``span`` returns a shared no-op context manager
-and ``instant``/``counter``/``gauge`` return immediately — instrumented hot
-paths pay one module-attribute load and one ``is None`` check.  Events must
-fire at the Python/driver level only (trace-time-safe: a jitted consumer
-emits execution events once per call, not once per trace), and enabling
-telemetry must never change compiled numerics.
+With neither sink active ``span`` returns a shared no-op context manager
+and ``instant``/``counter`` return immediately: an instrumented hot path
+pays a module-attribute load, one ``TraceAnnotation.is_enabled()`` call
+and one ``is None`` check.  Events must fire at the Python/driver level
+only (trace-time-safe: a jitted consumer emits execution events once per
+call, not once per trace), and neither sink may change compiled numerics.
+JAX is never imported here: the profiler sink is looked up only once
+``jax.profiler`` has been imported, since no session can collect before.
 
-Enabling telemetry also installs the ``jax.monitoring`` bridge
+Enabling the ring also installs the ``jax.monitoring`` bridge
 (:mod:`repro.core.telemetry.jaxmon`): XLA backend compiles become the
 ``jax.compile.backend_compile`` counter plus ``jax.compile`` spans, so
 recompile storms — the runtime twin of the static auditor's ``recompile``
@@ -40,31 +51,36 @@ pass — are visible in every trace.
 from __future__ import annotations
 
 import atexit
+import gc
 import os
+import sys
 from typing import Any, Dict, List, Optional
 
 from repro.core.telemetry.recorder import (DEFAULT_CAPACITY, NOOP_SPAN,
                                            Recorder, RingLog, SCHEMA,
                                            safe_attrs)
-from repro.core.telemetry.export import (chrome_trace, metrics_snapshot,
-                                         read_events, write_chrome_trace,
+from repro.core.telemetry.export import (metrics_snapshot, read_events,
                                          write_jsonl)
 from repro.core.telemetry.summarize import (format_summary, percentile,
                                             summarize_events, summarize_file)
 
 __all__ = [
     "SCHEMA", "ENV", "CAP_ENV", "Recorder", "RingLog", "configure",
-    "enabled", "recorder", "span", "instant", "counter", "gauge",
-    "snapshot", "reset", "safe_attrs", "write_jsonl", "write_chrome_trace",
-    "chrome_trace", "read_events", "metrics_snapshot", "summarize_file",
-    "summarize_events", "format_summary", "percentile", "DEFAULT_CAPACITY",
+    "enabled", "recorder", "span", "instant", "counter", "snapshot",
+    "reset", "safe_attrs", "write_jsonl", "read_events", "metrics_snapshot",
+    "summarize_file", "summarize_events", "format_summary", "percentile",
+    "DEFAULT_CAPACITY", "GC_SPAN",
 ]
 
 ENV = "REPRO_TELEMETRY"
 CAP_ENV = "REPRO_TELEMETRY_CAP"
+#: profiler span around each garbage collection
+GC_SPAN = "python.gc"
 
 _recorder: Optional[Recorder] = None      # None <=> disabled fast path
 _jsonl_path: Optional[str] = None
+#: ``jax.profiler.TraceAnnotation`` once ``jax.profiler`` is imported
+_annotation: Any = None
 
 
 def configure(mode: Optional[str] = None,
@@ -108,11 +124,27 @@ def recorder() -> Optional[Recorder]:
 
 
 # ---- recording fast paths ------------------------------------------------
+def _find_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` if ``jax.profiler`` has been
+    imported (never imports it: a gc callback may run mid-import)."""
+    global _annotation
+    _annotation = getattr(sys.modules.get("jax.profiler"),
+                          "TraceAnnotation", None)
+    return _annotation
+
+
 def span(name: str, proc: str = "main", **attrs: Any):
+    """Context manager around one operation, recorded in every active
+    sink: the ring, and the profiler's trace while a session collects."""
+    ann = _annotation or _find_annotation()
+    if ann is not None and not ann.is_enabled():
+        ann = None
     rec = _recorder
     if rec is None:
-        return NOOP_SPAN
-    return rec.span(name, proc=proc, **attrs)
+        return NOOP_SPAN if ann is None else ann(name, **attrs)
+    return rec.span(name, proc=proc,
+                    annotation=None if ann is None else ann(name, **attrs),
+                    **attrs)
 
 
 def instant(name: str, proc: str = "main", **attrs: Any) -> None:
@@ -125,12 +157,6 @@ def counter(name: str, value: float = 1.0, proc: str = "main") -> None:
     rec = _recorder
     if rec is not None:
         rec.counter(name, value, proc=proc)
-
-
-def gauge(name: str, value: float, proc: str = "main") -> None:
-    rec = _recorder
-    if rec is not None:
-        rec.gauge(name, value, proc=proc)
 
 
 def snapshot() -> Dict[str, Any]:
@@ -159,6 +185,26 @@ def flush(path: Optional[str] = None) -> Optional[str]:
         return None
     write_jsonl(target, rec)
     return target
+
+
+_gc_span: Any = None     # the open python.gc annotation, between callbacks
+
+
+def _gc_callback(phase: str, info: Dict[str, Any]) -> None:
+    """Bracket each collection in a ``python.gc`` profiler span, so that a
+    collector pause in a host loop shows as what held the device idle."""
+    global _gc_span
+    if phase == "start":
+        ann = _annotation or _find_annotation()
+        if ann is not None and ann.is_enabled():
+            _gc_span = ann(GC_SPAN, generation=info["generation"])
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        opened, _gc_span = _gc_span, None
+        opened.__exit__(None, None, None)
+
+
+gc.callbacks.append(_gc_callback)
 
 
 @atexit.register

@@ -1,4 +1,4 @@
-"""Structured-event recorder: spans, counters, gauges in a bounded ring.
+"""Structured-event recorder: spans and counters in a bounded ring.
 
 The paper's Eq.-4 portability metric and the serving SLO report are only as
 trustworthy as the instrumentation behind them, so every measured number in
@@ -7,8 +7,8 @@ which parameters*, nested inside *which* larger operation.  This module is
 the zero-dependency (stdlib-only) substrate for that:
 
   * :class:`Recorder` holds a thread-safe bounded ring buffer of event
-    dicts (schema ``repro.telemetry/v1``) plus aggregated counters and
-    last-value gauges that never suffer ring eviction;
+    dicts (schema ``repro.telemetry/v1``) plus aggregated counters that
+    never suffer ring eviction;
   * spans measure ``time.perf_counter()`` start/duration and nest — each
     thread keeps its own span stack, so a child span records its parent's
     id and exporters can rebuild the tree;
@@ -17,7 +17,7 @@ the zero-dependency (stdlib-only) substrate for that:
 
 Event fields (all events)::
 
-    kind   "span" | "instant" | "counter" | "gauge"
+    kind   "span" | "instant" | "counter"
     name   dotted event name ("serving.decode_step", "tuning.cache.hit")
     ts     seconds since recorder epoch (monotonic)
     proc   logical process/track label ("engine", "tuning", ...)
@@ -25,7 +25,7 @@ Event fields (all events)::
     attrs  {str: scalar} tags (kernel, backend, uid, ...)
 
 plus ``dur`` (seconds) / ``sid`` / ``parent`` on spans and ``value`` on
-counter/gauge samples.
+counter samples.
 
 Instrumented hot paths must stay trace-time-safe: record only at the
 Python/driver level (around ``jit`` calls, never inside traced code), so an
@@ -72,12 +72,15 @@ def safe_attrs(attrs: Dict[str, Any]) -> Dict[str, Any]:
 
 
 class _Span:
-    """Context manager recording one span event on exit."""
+    """Context manager recording one span event on exit; ``annotation``, a
+    profiler ``TraceAnnotation`` of the same span, opens and closes with
+    it."""
 
-    __slots__ = ("_rec", "name", "proc", "attrs", "sid", "parent", "_t0")
+    __slots__ = ("_rec", "name", "proc", "attrs", "sid", "parent", "_t0",
+                 "_annotation")
 
     def __init__(self, rec: "Recorder", name: str, proc: str,
-                 attrs: Dict[str, Any]):
+                 attrs: Dict[str, Any], annotation: Any = None):
         self._rec = rec
         self.name = name
         self.proc = proc
@@ -85,16 +88,21 @@ class _Span:
         self.sid = next(rec._ids)
         self.parent: Optional[int] = None
         self._t0 = 0.0
+        self._annotation = annotation
 
     def __enter__(self) -> "_Span":
         stack = self._rec._stack()
         self.parent = stack[-1] if stack else None
         stack.append(self.sid)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         stack = self._rec._stack()
         if stack and stack[-1] == self.sid:
             stack.pop()
@@ -124,13 +132,12 @@ NOOP_SPAN = NoopSpan()
 
 
 class Recorder:
-    """Thread-safe bounded event ring + counter/gauge aggregates."""
+    """Thread-safe bounded event ring + counter aggregates."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY) -> None:
         self.capacity = int(capacity)
         self.events: Deque[Dict[str, Any]] = deque(maxlen=self.capacity)
         self.counters: Dict[str, float] = {}
-        self.gauges: Dict[str, float] = {}
         self.dropped = 0                     # events evicted from the ring
         self.epoch = time.perf_counter()     # monotonic zero for ts fields
         self.epoch_unix = time.time()        # wall-clock provenance
@@ -155,8 +162,9 @@ class Recorder:
         return time.perf_counter() - self.epoch
 
     # ---- recording API -------------------------------------------------
-    def span(self, name: str, proc: str = "main", **attrs: Any) -> _Span:
-        return _Span(self, name, proc, safe_attrs(attrs))
+    def span(self, name: str, proc: str = "main", *,
+             annotation: Any = None, **attrs: Any) -> _Span:
+        return _Span(self, name, proc, safe_attrs(attrs), annotation)
 
     def instant(self, name: str, proc: str = "main", **attrs: Any) -> None:
         stack = self._stack()
@@ -170,7 +178,7 @@ class Recorder:
     def counter(self, name: str, value: float = 1.0,
                 proc: str = "main") -> float:
         """Increment an aggregated counter (and log the new total as a
-        counter sample so Chrome tracing can draw the track)."""
+        counter sample in the ring)."""
         with self._lock:
             total = self.counters.get(name, 0.0) + value
             self.counters[name] = total
@@ -182,20 +190,6 @@ class Recorder:
                 "tid": threading.current_thread().name, "attrs": {},
             })
         return total
-
-    def gauge(self, name: str, value: float, proc: str = "main") -> None:
-        """Record the current value of a sampled quantity (queue depth,
-        slot occupancy).  Last value wins in the snapshot; every sample
-        lands in the ring for the trace timeline."""
-        with self._lock:
-            self.gauges[name] = float(value)
-            if len(self.events) == self.capacity:
-                self.dropped += 1
-            self.events.append({
-                "kind": "gauge", "name": name, "ts": self._now(),
-                "value": float(value), "proc": proc,
-                "tid": threading.current_thread().name, "attrs": {},
-            })
 
     # ---- reading -------------------------------------------------------
     def drain(self) -> List[Dict[str, Any]]:
@@ -211,12 +205,11 @@ class Recorder:
 
     def snapshot(self) -> Dict[str, Any]:
         """Flat metrics dict benchmarks can embed in their artifacts:
-        counters, gauges (last value), per-span-name count/total, and the
-        ring-eviction count (so a truncated trace is visible as such)."""
+        counters, per-span-name count/total, and the ring-eviction count
+        (so a truncated trace is visible as such)."""
         with self._lock:
             events = list(self.events)
             counters = dict(self.counters)
-            gauges = dict(self.gauges)
             dropped = self.dropped
         spans: Dict[str, Dict[str, float]] = {}
         for ev in events:
@@ -225,15 +218,13 @@ class Recorder:
             agg = spans.setdefault(ev["name"], {"count": 0, "total_s": 0.0})
             agg["count"] += 1
             agg["total_s"] += ev["dur"]
-        return {"schema": SCHEMA, "counters": counters, "gauges": gauges,
-                "spans": spans, "events_recorded": len(events),
-                "events_dropped": dropped}
+        return {"schema": SCHEMA, "counters": counters, "spans": spans,
+                "events_recorded": len(events), "events_dropped": dropped}
 
     def clear(self) -> None:
         with self._lock:
             self.events.clear()
             self.counters.clear()
-            self.gauges.clear()
             self.dropped = 0
 
 

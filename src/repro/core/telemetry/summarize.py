@@ -1,7 +1,7 @@
 """Per-span-name latency summary of a recorded trace.
 
 ``python -m repro.core.telemetry summarize <trace>`` prints, for every span
-name in a JSONL event log or Chrome ``trace.json``::
+name in a JSONL event log::
 
     name  count  total_ms  p50_ms  p95_ms  p99_ms
 
@@ -62,7 +62,6 @@ def summarize_file(path: str) -> Dict[str, Any]:
         "schema": doc["header"].get("schema", "?"),
         "spans": summarize_events(doc["events"]),
         "counters": doc["footer"].get("counters", {}),
-        "gauges": doc["footer"].get("gauges", {}),
         "events": len(doc["events"]),
         "events_dropped": doc["footer"].get("events_dropped", 0),
     }
@@ -100,7 +99,7 @@ def main(argv: List[str]) -> int:
     sub = ap.add_subparsers(dest="cmd", required=True)
     s = sub.add_parser("summarize",
                        help="per-span count/total/p50/p95/p99 of a trace")
-    s.add_argument("trace", help="JSONL event log or Chrome trace.json")
+    s.add_argument("trace", help="JSONL event log")
     s.add_argument("--json", action="store_true",
                    help="machine-readable output instead of the table")
     args = ap.parse_args(argv)

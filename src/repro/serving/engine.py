@@ -48,14 +48,26 @@ and SSM/hybrid state caches and encoder-decoder memory are per-request state
 this slot scatter does not yet carry; MoE capacity routing is batch-coupled,
 so MoE outputs can differ from unbatched decode.
 
-Telemetry: when ``REPRO_TELEMETRY`` is on, the engine emits a full request
-lifecycle on the ``engine`` track — ``serving.enqueue`` ->
-``serving.slot_assign`` -> a ``serving.prefill`` span -> ``serving.first_token``
--> per-step ``serving.decode_step`` spans -> ``serving.finish`` — plus
-``serving.queue_depth`` / ``serving.slot_occupancy`` gauges sampled per
-step.  All events fire at the Python driver level around the compiled
-programs, never inside them: enabling telemetry changes no compiled shape
-and no sampled token (bitwise-neutral by construction).
+Telemetry (``repro.core.telemetry``): the request lifecycle is a chain of
+instants on the ``engine`` track — ``serving.enqueue`` ->
+``serving.slot_assign`` -> ``serving.first_token`` -> ``serving.finish`` —
+recorded in the ring when ``REPRO_TELEMETRY`` is on.  The host work is
+split into spans, recorded in the ring and, while a JAX profiler session
+collects, on the trace's ``/host:CPU`` plane beside the device's programs::
+
+    serving.submit       submit(): validation, the key's fold_in, enqueue
+    serving.step         step(): admissions, then one decode step
+      serving.admit      _admit(): one request into its slot
+        serving.prefill  the prefill call and its wait
+          serving.prefill.wait     int(tok0), blocking on the device
+      serving.decode_step          _decode_once() for a non-empty batch
+        serving.decode.dispatch    key splits, uploads, the decode call
+        serving.decode.wait        np.asarray(toks), blocking on the device
+        serving.decode.emit        per-slot token loop and any finish
+
+``serving.decode_step`` carries ``step``, ``active`` and ``queued``.  Every
+span opens and closes in Python around the compiled programs, never inside
+them: telemetry changes no compiled shape and no sampled token.
 """
 
 from __future__ import annotations
@@ -264,14 +276,16 @@ class ServingEngine:
                     f"{self.balloc.capacity()}")
 
     def submit(self, req: Request) -> None:
-        self._validate(req)
-        if req.key is None:
-            req.key = jax.random.fold_in(self._base_key, req.uid)
-        self.queue.submit(req)
-        tel.instant("serving.enqueue", proc="engine", uid=req.uid,
-                    prompt_len=req.prompt_len,
-                    max_new_tokens=req.max_new_tokens,
-                    queue_depth=len(self.queue))
+        with tel.span("serving.submit", proc="engine", uid=req.uid,
+                      prompt_len=req.prompt_len):
+            self._validate(req)
+            if req.key is None:
+                req.key = jax.random.fold_in(self._base_key, req.uid)
+            self.queue.submit(req)
+            tel.instant("serving.enqueue", proc="engine", uid=req.uid,
+                        prompt_len=req.prompt_len,
+                        max_new_tokens=req.max_new_tokens,
+                        queue_depth=len(self.queue))
 
     def _has_capacity(self, req: Request) -> bool:
         """Can `req` be admitted right now?  A free slot always; the paged
@@ -305,51 +319,55 @@ class ServingEngine:
     def _admit(self, req: Request, now: float,
                finished: List[Request]) -> None:
         slot = self.slots.alloc()
-        self.slot_req[slot] = req
-        req.t_admitted = now
-        tel.instant("serving.slot_assign", proc="engine", uid=req.uid,
-                    slot=slot, queued_s=now - req.arrival_time)
         L = req.prompt_len
         bucket = self._bucket_for(L)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, bucket - L:] = req.prompt                # left-pad
-        if self.temperature > 0.0:
-            req.key, sub = jax.random.split(req.key)
-        else:
-            sub = req.key       # greedy: sample() never consumes the key
-        if self.cache_layout == "paged":
-            n_pages = self.balloc.blocks_for(L, req.max_new_tokens)
-            pages = self.balloc.alloc(n_pages)           # full lifetime up
-            self._slot_blocks[slot] = pages              # front: decode never
-            row = np.full(self.pages_per_slot, SENTINEL_BLOCK, np.int32)
-            row[:n_pages] = pages                        # hits an unowned page
-            self.block_tables[slot] = row
-        with tel.span("serving.prefill", proc="engine", uid=req.uid,
+        with tel.span("serving.admit", proc="engine", uid=req.uid,
                       slot=slot, prompt_len=L, bucket=bucket):
-            if self.cache_layout == "paged":
-                tok0, self.caches = self._prefill(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray([L], jnp.int32), jnp.asarray(row),
-                    np.int32(slot), sub, self.caches)
+            self.slot_req[slot] = req
+            req.t_admitted = now
+            tel.instant("serving.slot_assign", proc="engine", uid=req.uid,
+                        slot=slot, queued_s=now - req.arrival_time)
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, bucket - L:] = req.prompt                # left-pad
+            if self.temperature > 0.0:
+                req.key, sub = jax.random.split(req.key)
             else:
-                tok0, self.caches = self._prefill(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray([L], jnp.int32), np.int32(slot), sub,
-                    self.caches)
-            tok0 = int(tok0)     # device sync: the span covers the wait
-        self.stats["prefill_calls"] += 1
-        now = self._clock()
-        req.t_first_token = now
-        req.t_tokens.append(now)
-        tel.instant("serving.first_token", proc="engine", uid=req.uid,
-                    slot=slot, ttft_s=now - req.arrival_time)
-        req.generated.append(tok0)
-        self.stats["tokens_generated"] += 1
-        if len(req.generated) >= req.max_new_tokens or tok0 == req.eos_id:
-            self._finish(slot, req, now, finished)
-            return
-        self.tok_buf[slot, 0] = tok0
-        self.pos_buf[slot, 0] = L        # true length, not padded length
+                sub = req.key   # greedy: sample() never consumes the key
+            if self.cache_layout == "paged":
+                n_pages = self.balloc.blocks_for(L, req.max_new_tokens)
+                pages = self.balloc.alloc(n_pages)       # full lifetime up
+                self._slot_blocks[slot] = pages          # front: decode never
+                row = np.full(self.pages_per_slot, SENTINEL_BLOCK, np.int32)
+                row[:n_pages] = pages                    # hits an unowned page
+                self.block_tables[slot] = row
+            with tel.span("serving.prefill", proc="engine", uid=req.uid,
+                          slot=slot, prompt_len=L, bucket=bucket):
+                if self.cache_layout == "paged":
+                    tok0, self.caches = self._prefill(
+                        self.params, jnp.asarray(toks),
+                        jnp.asarray([L], jnp.int32), jnp.asarray(row),
+                        np.int32(slot), sub, self.caches)
+                else:
+                    tok0, self.caches = self._prefill(
+                        self.params, jnp.asarray(toks),
+                        jnp.asarray([L], jnp.int32), np.int32(slot), sub,
+                        self.caches)
+                with tel.span("serving.prefill.wait", proc="engine"):
+                    tok0 = int(tok0)     # device sync
+            self.stats["prefill_calls"] += 1
+            now = self._clock()
+            req.t_first_token = now
+            req.t_tokens.append(now)
+            tel.instant("serving.first_token", proc="engine", uid=req.uid,
+                        slot=slot, ttft_s=now - req.arrival_time)
+            req.generated.append(tok0)
+            self.stats["tokens_generated"] += 1
+            if (len(req.generated) >= req.max_new_tokens
+                    or tok0 == req.eos_id):
+                self._finish(slot, req, now, finished)
+                return
+            self.tok_buf[slot, 0] = tok0
+            self.pos_buf[slot, 0] = L    # true length, not padded length
 
     # ------------------------------------------------------------------
     def _decode_once(self, finished: List[Request]) -> int:
@@ -358,65 +376,70 @@ class ServingEngine:
         active = self.active_count()
         if active == 0:
             return 0
-        n0 = len(finished)
-        tel.gauge("serving.queue_depth", len(self.queue), proc="engine")
-        tel.gauge("serving.slot_occupancy", active / self.num_slots,
-                  proc="engine")
-        keys = np.zeros((self.num_slots, 2), np.uint32)
-        if self.temperature > 0.0:      # greedy path never reads the keys
-            for s, req in enumerate(self.slot_req):
-                if req is not None:
-                    req.key, sub = jax.random.split(req.key)
-                    keys[s] = np.asarray(sub)
-        with tel.span("serving.decode_step", proc="engine", active=active,
-                      step=self.stats["decode_steps"]):
-            if self.cache_layout == "paged":
-                toks, self.caches = self._decode(
-                    self.params, jnp.asarray(self.tok_buf),
-                    jnp.asarray(self.pos_buf), jnp.asarray(keys),
-                    self.caches, jnp.asarray(self.block_tables))
-            else:
-                toks, self.caches = self._decode(
-                    self.params, jnp.asarray(self.tok_buf),
-                    jnp.asarray(self.pos_buf), jnp.asarray(keys), self.caches)
-            toks = np.asarray(toks)      # device sync inside the span
-        self.stats["decode_steps"] += 1
-        now = self._clock()
-        for s, req in enumerate(self.slot_req):
-            if req is None:                      # inactive slot: token ignored
-                continue
-            t = int(toks[s])
-            req.generated.append(t)
-            req.t_tokens.append(now)
-            self.stats["tokens_generated"] += 1
-            if len(req.generated) >= req.max_new_tokens or t == req.eos_id:
-                self._finish(s, req, now, finished)
-            else:
-                self.tok_buf[s, 0] = t
-                self.pos_buf[s, 0] += 1
-        return len(finished) - n0
+        with tel.span("serving.decode_step", proc="engine",
+                      step=self.stats["decode_steps"], active=active,
+                      queued=len(self.queue)):
+            n0 = len(finished)
+            with tel.span("serving.decode.dispatch", proc="engine"):
+                keys = np.zeros((self.num_slots, 2), np.uint32)
+                if self.temperature > 0.0:   # greedy never reads the keys
+                    for s, req in enumerate(self.slot_req):
+                        if req is not None:
+                            req.key, sub = jax.random.split(req.key)
+                            keys[s] = np.asarray(sub)
+                if self.cache_layout == "paged":
+                    toks, self.caches = self._decode(
+                        self.params, jnp.asarray(self.tok_buf),
+                        jnp.asarray(self.pos_buf), jnp.asarray(keys),
+                        self.caches, jnp.asarray(self.block_tables))
+                else:
+                    toks, self.caches = self._decode(
+                        self.params, jnp.asarray(self.tok_buf),
+                        jnp.asarray(self.pos_buf), jnp.asarray(keys),
+                        self.caches)
+            with tel.span("serving.decode.wait", proc="engine"):
+                toks = np.asarray(toks)      # device sync
+            self.stats["decode_steps"] += 1
+            now = self._clock()
+            with tel.span("serving.decode.emit", proc="engine"):
+                for s, req in enumerate(self.slot_req):
+                    if req is None:          # inactive slot: token ignored
+                        continue
+                    t = int(toks[s])
+                    req.generated.append(t)
+                    req.t_tokens.append(now)
+                    self.stats["tokens_generated"] += 1
+                    if (len(req.generated) >= req.max_new_tokens
+                            or t == req.eos_id):
+                        self._finish(s, req, now, finished)
+                    else:
+                        self.tok_buf[s, 0] = t
+                        self.pos_buf[s, 0] += 1
+            return len(finished) - n0
 
     def step(self, now: Optional[float] = None) -> List[Request]:
         """Admit ready requests into free slots, then decode one token for
         every slot.  Returns the requests that finished this step."""
-        if now is None:
-            now = self._clock()
-        finished: List[Request] = []
-        first = True
-        while self.slots.available():
-            if not first:
-                # prefill takes real time: recompute the clock so later
-                # admits in the same step get honest t_admitted/queued_s and
-                # requests that arrived mid-prefill are checked now, not
-                # next step (stale-`now` admission bug)
-                now = max(now, self._clock())
-            head = self.queue.peek_ready(now)
-            if head is None or not self._has_capacity(head):
-                break                    # FIFO head-of-line: no queue jumping
-            self._admit(self.queue.pop_ready(now), now, finished)
-            first = False
-        self._decode_once(finished)
-        return finished
+        with tel.span("serving.step", proc="engine",
+                      step=self.stats["decode_steps"]):
+            if now is None:
+                now = self._clock()
+            finished: List[Request] = []
+            first = True
+            while self.slots.available():
+                if not first:
+                    # prefill takes real time: recompute the clock so later
+                    # admits in the same step get honest t_admitted/queued_s
+                    # and requests that arrived mid-prefill are checked now,
+                    # not next step (stale-`now` admission bug)
+                    now = max(now, self._clock())
+                head = self.queue.peek_ready(now)
+                if head is None or not self._has_capacity(head):
+                    break                # FIFO head-of-line: no queue jumping
+                self._admit(self.queue.pop_ready(now), now, finished)
+                first = False
+            self._decode_once(finished)
+            return finished
 
     def run(self, requests: Sequence[Request]) -> List[Request]:
         """Serve a trace to completion, synchronously.  Resets the engine

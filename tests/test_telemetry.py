@@ -1,12 +1,17 @@
 """Telemetry-core tests: span nesting, ring eviction, disabled no-op,
-Chrome-trace export, the once-per-call (not once-per-trace) regression, the
-bounded attention dispatch stream, the serving SLO percentiles, and the
-summarize CLI smoke on a trace emitted by a real engine run."""
+spans on the JAX profiler's trace (engine spans, ``python.gc``), the
+once-per-call (not once-per-trace) regression, the bounded attention
+dispatch stream, the serving SLO percentiles, and the summarize CLI smoke
+on a trace emitted by a real engine run."""
 
+import gc
+import glob
 import json
 import os
+import pathlib
 import subprocess
 import sys
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +21,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import telemetry as tel
 from repro.core.telemetry import jaxmon
+from repro.core.telemetry.recorder import NOOP_SPAN
 from repro.models import attention as A
 from repro.models import transformer as T
 from repro.serving import Request, ServingEngine
@@ -86,11 +92,10 @@ def test_disabled_mode_is_noop():
     tel.configure("off")
     assert not tel.enabled() and tel.recorder() is None
     # shared stateless context manager — no per-call allocation
-    assert tel.span("a", proc="x", k=1) is tel.span("b")
+    assert tel.span("a", proc="x", k=1) is tel.span("b") is NOOP_SPAN
     with tel.span("a"):
         tel.instant("i")
         tel.counter("c")
-        tel.gauge("g", 1.0)
     assert tel.events() == [] and tel.snapshot() == {}
     rec = tel.configure("on")
     tel.instant("now-recording")
@@ -106,37 +111,69 @@ def test_configure_rejects_bad_mode():
         tel.configure("jsonl:")
     assert not tel.enabled()
 
+# --------------------------------------------------------------------------
+# the profiler sink: spans on the trace's /host:CPU plane
+# --------------------------------------------------------------------------
+def _profile(tmp_path, body):
+    """Run ``body`` under a JAX profiler session; return the host events of
+    the trace as (line, name, start_ns, end_ns, stats) tuples."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    with warnings.catch_warnings():   # jaxlib's event_stats type warns
+        warnings.simplefilter("ignore", DeprecationWarning)
+        return [(line.name, e.name, e.start_ns, e.start_ns + e.duration_ns,
+                 dict(e.stats))
+                for plane in data.planes if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events]
+
+
+def _named(events, name):
+    return [e for e in events if e[1] == name]
+
+
+def _inside(inner, outer):
+    return inner[0] == outer[0] and outer[2] <= inner[2] <= inner[3] <= \
+        outer[3]
+
+
+@pytest.mark.parametrize("ring", ["off", "on"])
+def test_spans_land_on_the_profiler_trace(ring, tmp_path):
+    rec = tel.configure(ring)
+    try:
+        def body():
+            with tel.span("t.outer", proc="t", uid=7, kind="x"):
+                with tel.span("t.inner", proc="t", step=3):
+                    jax.block_until_ready(jnp.ones(4) + 1.0)
+        events = _profile(tmp_path, body)
+        after = tel.span("t.after")
+    finally:
+        tel.configure(os.environ.get(tel.ENV))
+    outer, = _named(events, "t.outer")
+    inner, = _named(events, "t.inner")
+    assert outer[4] == {"uid": 7, "kind": "x"} and inner[4] == {"step": 3}
+    assert _inside(inner, outer)
+    if ring == "on":            # the ring records as before, beside it
+        assert [e["name"] for e in rec.event_list()] == ["t.inner",
+                                                         "t.outer"]
+        assert after is not NOOP_SPAN
+    else:                       # no profiler, no ring: the shared no-op
+        assert after is NOOP_SPAN
+
+
+def test_gc_collection_is_a_profiler_span(tmp_path):
+    events = _profile(tmp_path, lambda: gc.collect(2))
+    spans = _named(events, tel.GC_SPAN)
+    assert spans and any(e[4] == {"generation": 2} for e in spans)
+
 
 # --------------------------------------------------------------------------
 # exporters
 # --------------------------------------------------------------------------
-def test_chrome_trace_round_trips(telem, tmp_path):
-    with tel.span("work", proc="engine", kernel="stencil7"):
-        with tel.span("child", proc="engine"):
-            pass
-    tel.gauge("depth", 3.0, proc="engine")
-    tel.instant("mark", proc="worker", uid=1)
-    path = tmp_path / "trace.json"
-    tel.write_chrome_trace(str(path), telem)
-    doc = json.loads(path.read_text())          # well-formed JSON
-    tes = doc["traceEvents"]
-    xs = [t for t in tes if t["ph"] == "X"]
-    assert {t["name"] for t in xs} == {"work", "child"}
-    for t in xs:
-        assert isinstance(t["ts"], float) and isinstance(t["dur"], float)
-        assert t["dur"] >= 0.0 and isinstance(t["pid"], int)
-    cs = [t for t in tes if t["ph"] == "C"]
-    assert cs and cs[0]["args"] == {"depth": 3.0}
-    assert any(t["ph"] == "i" and t["name"] == "mark" for t in tes)
-    # proc labels become named processes via metadata events
-    procs = {t["args"]["name"] for t in tes
-             if t["ph"] == "M" and t["name"] == "process_name"}
-    assert {"engine", "worker"} <= procs
-    # and the summarize CLI reads the chrome form too
-    summary = tel.summarize_file(str(path))
-    assert summary["spans"]["work"]["count"] == 1
-
-
 def test_jsonl_round_trip_and_summary(telem, tmp_path):
     for i in range(10):
         with tel.span("op", proc="t", i=i):
@@ -281,9 +318,9 @@ def test_latency_summary_p99_and_itl():
 # --------------------------------------------------------------------------
 # engine lifecycle + CLI smoke (tier-1: tiny synthetic engine run)
 # --------------------------------------------------------------------------
-def _run_engine(params, n=3):
-    eng = ServingEngine(params, CFG, num_slots=2, cache_len=32,
-                        prefill_len=8)
+def _run_engine(params, eng=None, n=3):
+    eng = eng or ServingEngine(params, CFG, num_slots=2, cache_len=32,
+                               prefill_len=8)
     reqs = [Request(uid=i,
                     prompt=np.arange(2 + i, 6 + i, dtype=np.int32),
                     max_new_tokens=4) for i in range(n)]
@@ -310,15 +347,16 @@ def test_engine_lifecycle_events_and_cli_smoke(params, tmp_path):
         assert len(by_name[name]) == 3, name
     assert len(by_name["serving.prefill"]) == 3
     assert by_name["serving.decode_step"], "no decode-step spans"
-    # decode steps nest under the serving.run span
+    # decode steps nest under engine steps, which nest under serving.run
     run_sid = by_name["serving.run"][0]["sid"]
-    assert all(e["parent"] == run_sid
+    step_sids = {e["sid"] for e in by_name["serving.step"]}
+    assert all(e["parent"] == run_sid for e in by_name["serving.step"])
+    assert all(e["parent"] in step_sids
                for e in by_name["serving.decode_step"])
-    # gauges sampled per step
-    assert len(by_name["serving.queue_depth"]) == \
-        len(by_name["serving.decode_step"])
-    assert all(0 < e["value"] <= 1.0
-               for e in by_name["serving.slot_occupancy"])
+    # the step's batch and queue ride on the decode span's attributes
+    steps = [e["attrs"] for e in by_name["serving.decode_step"]]
+    assert [a["step"] for a in steps] == list(range(len(steps)))
+    assert all(0 < a["active"] <= 2 and a["queued"] >= 0 for a in steps)
     # lifecycle ordering per request uid
     for uid in range(3):
         ts = {n: [e["ts"] for e in by_name[n]
@@ -346,6 +384,55 @@ def test_engine_lifecycle_events_and_cli_smoke(params, tmp_path):
     assert "serving.decode_step" in out.stdout
     assert "p99_ms" in out.stdout or "p99" in out.stdout
     assert "serving.requests_finished = 3" in out.stdout
+
+
+def test_engine_spans_on_the_profiler_trace(params, tmp_path):
+    assert not tel.enabled()
+    toks_off = _run_engine(params)
+    eng = ServingEngine(params, CFG, num_slots=2, cache_len=32,
+                        prefill_len=8)
+    before = dict(eng.stats)
+    got = {}
+    events = _profile(tmp_path, lambda: got.update(_run_engine(params, eng)))
+    assert got == toks_off          # greedy tokens bitwise, profiler on/off
+    grew = {k: eng.stats[k] - before[k] for k in eng.stats}
+    steps = _named(events, "serving.decode_step")
+    admits = _named(events, "serving.admit")
+    assert len(steps) == grew["decode_steps"] > 0
+    assert len(admits) == grew["prefill_calls"] == 3
+    assert len(_named(events, "serving.submit")) == 3
+    assert [e[4]["step"] for e in steps] == list(range(len(steps)))
+    assert all(0 < e[4]["active"] <= 2 and "queued" in e[4] for e in steps)
+    for child in ("serving.decode.dispatch", "serving.decode.wait",
+                  "serving.decode.emit"):
+        kids = _named(events, child)
+        assert len(kids) == len(steps), child
+        assert all(sum(_inside(k, s) for s in steps) == 1 for k in kids)
+    prefills = _named(events, "serving.prefill")
+    waits = _named(events, "serving.prefill.wait")
+    assert len(prefills) == len(waits) == 3
+    assert all(any(_inside(w, p) for p in prefills) for w in waits)
+    assert all(any(_inside(p, a) for a in admits) for p in prefills)
+    assert {e[4]["uid"] for e in admits} == {0, 1, 2}
+    engine_steps = _named(events, "serving.step")
+    assert all(any(_inside(s, t) for t in engine_steps) for s in steps)
+
+
+def test_engine_programs_keep_the_names_the_mfu_readers_read(params):
+    eng = ServingEngine(params, CFG, num_slots=2, cache_len=32,
+                        prefill_len=8)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    prefill = eng._prefill.lower(eng.params, toks, jnp.asarray([4], jnp.int32),
+                                 np.int32(0), eng._base_key, eng.caches)
+    decode = eng._decode.lower(eng.params, jnp.asarray(eng.tok_buf),
+                               jnp.asarray(eng.pos_buf),
+                               jnp.zeros((2, 2), jnp.uint32), eng.caches)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    for lowered, module, reader in ((prefill, "jit_prefill_fn", "prefill_mfu"),
+                                    (decode, "jit_decode_fn", "decode_mfu")):
+        assert lowered.as_text().startswith(f"module @{module} "), module
+        text = (root / "bench" / "metrics" / f"{reader}.py").read_text()
+        assert f'"{module}"' in text, reader
 
 
 # --------------------------------------------------------------------------
@@ -395,12 +482,10 @@ def test_serving_benchmark_smoke_writes_v4_artifact(tmp_path, monkeypatch):
         if row["backend"] != "xla":
             assert row["dispatch"]["decode"]["backend"] != "xla"
 
-    # trace artifacts: JSONL summarizes, chrome form loads
+    # trace artifact: the JSONL log summarizes
     summary = tel.summarize_file(artifact["trace_jsonl"])
     assert summary["spans"]["serving.decode_step"]["count"] > 0
     assert summary["counters"][jaxmon.COMPILE_COUNTER] == \
         on_disk["jax_compile_events"]
-    doc = json.loads(open(artifact["trace_chrome"]).read())
-    assert any(t["ph"] == "X" for t in doc["traceEvents"])
     # telemetry was owned by the benchmark and is off again
     assert not tel.enabled()
