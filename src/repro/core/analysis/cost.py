@@ -10,6 +10,9 @@ it.  Three execution-free passes over the same closed-jaxpr traces:
      expensive ``cond`` branch wins).  Pallas BlockSpecs are costed by the
      same index-map enumeration as the grid pass, so halo *re-reads* and
      accumulator *revisits* are counted as real traffic, not wished away.
+     An operand left in HBM (``pl.ANY``) is costed by the kernel's own
+     ``dma_start`` copies at the body's multiplicity; a copy under
+     ``pl.when`` counts on every step, an upper bound like ``cond``.
      The jaxpr boundary (invars + consts + outvars) is the minimum-traffic
      floor; ``inflation = traffic / floor`` is the "how many times over the
      compulsory bytes does this kernel move" number, and a cell whose
@@ -210,9 +213,11 @@ def _clipped_block_bytes(bi: Tuple[int, ...], block: Tuple[int, ...],
     return elems * itemsize
 
 
-def _pallas_traffic(gm: Any, mult: float, t: Traffic) -> float:
+def _pallas_traffic(gm: Any, mult: float,
+                    t: Traffic) -> Tuple[float, float]:
     """Blockwise HBM traffic of one pallas_call; returns the grid-step count
-    (the body multiplicity for the FLOP walk)."""
+    (the body multiplicity for the FLOP and DMA walk) and the bytes of the
+    inputs left in HBM, which the body's DMAs move."""
     grid = tuple(int(g) for g in (getattr(gm, "grid", ()) or ()))
     steps = _prod(grid) if grid else 1.0
     mappings = [bm for bm in gm.block_mappings if bm is not None]
@@ -220,7 +225,12 @@ def _pallas_traffic(gm: Any, mult: float, t: Traffic) -> float:
     enumerable = 0 < steps <= MAX_GRID_POINTS
     if not enumerable:
         t.approx_grids += 1
+    hbm_in = 0.0
     for bm in mappings:
+        if JU.in_hbm(bm.block_aval):
+            if id(bm) not in out_ids:
+                hbm_in += _aval_bytes(bm.array_aval) * mult
+            continue
         block = JU.blocked_dims(bm)
         if block is None:
             continue  # element / bounded-slice indexing: not modeled
@@ -255,7 +265,7 @@ def _pallas_traffic(gm: Any, mult: float, t: Traffic) -> float:
         else:
             t.hbm_read_bytes += total * mult
             t.reread_bytes += extra * mult
-    return max(steps, 1.0)
+    return max(steps, 1.0), hbm_in
 
 
 def _walk(jaxpr: Any, mult: float, t: Traffic) -> None:
@@ -291,13 +301,22 @@ def _walk(jaxpr: Any, mult: float, t: Traffic) -> None:
         elif name == "pallas_call":
             t.pallas_calls += 1
             gm = eqn.params.get("grid_mapping")
-            steps = 1.0
+            steps, hbm_in = 1.0, 0.0
             if gm is not None:
-                steps = _pallas_traffic(gm, mult, t)
+                steps, hbm_in = _pallas_traffic(gm, mult, t)
                 t.grid_steps += steps * mult
             body = eqn.params.get("jaxpr")
             if body is not None:
+                read0 = t.hbm_read_bytes
                 _walk(getattr(body, "jaxpr", body), mult * steps, t)
+                # DMA'd bytes beyond one pass over the HBM inputs: re-reads
+                t.reread_bytes += max(0.0, t.hbm_read_bytes - read0 - hbm_in)
+        elif name == "dma_start":
+            nbytes, src_hbm, dst_hbm = JU.dma_copy(eqn)
+            if src_hbm:
+                t.hbm_read_bytes += nbytes * mult
+            if dst_hbm:
+                t.hbm_write_bytes += nbytes * mult
         elif name in JU.PSUM_PRIMITIVES or name in ("ppermute", "all_to_all",
                                                     "reduce_scatter"):
             payload = sum(_aval_bytes(v.aval) for v in eqn.invars

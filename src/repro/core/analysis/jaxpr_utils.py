@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
+import numpy as np
 from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 #: ``jax.shard_map`` spells psum ``psum_invariant`` under ``check_vma=True``;
@@ -123,6 +124,23 @@ def blocked_dims(bm: Any) -> Optional[Tuple[int, ...]]:
         else:
             return None
     return tuple(dims)
+
+
+def in_hbm(aval: Any) -> bool:
+    """Whether a Pallas ref or block lives in HBM (``pl.ANY`` or HBM): such
+    an operand is not pipelined block by block but moved by the kernel's
+    own DMAs."""
+    return str(getattr(aval, "memory_space", "")).lower() in ("any", "hbm")
+
+
+def dma_copy(eqn: Any) -> Tuple[int, bool, bool]:
+    """(bytes, source in HBM, destination in HBM) of a ``dma_start`` eqn,
+    from the shapes of the sliced source and destination refs."""
+    src, _, dst, dst_tf = jax.tree_util.tree_unflatten(
+        eqn.params["tree"], [v.aval for v in eqn.invars])[:4]
+    shape = dst_tf[-1].get_indexer_shape() if dst_tf else dst.shape
+    nbytes = int(np.prod(shape, dtype=np.int64)) * np.dtype(dst.dtype).itemsize
+    return nbytes, in_hbm(src), in_hbm(dst)
 
 
 def output_block_mappings(grid_mapping: Any) -> List[Tuple[int, Any]]:

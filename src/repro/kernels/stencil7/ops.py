@@ -16,7 +16,7 @@ from repro.kernels.stencil7 import ref
 @functools.partial(jax.jit, static_argnames=(
     "invhx2", "invhy2", "invhz2", "invhxyz2", "by", "interpret"))
 def laplacian_pallas(u, invhx2=1.0, invhy2=1.0, invhz2=1.0, invhxyz2=-6.0,
-                     *, by=K.DEFAULT_BY, interpret=False):
+                     *, by=None, interpret=False):
     return K.laplacian_3d(u, invhx2, invhy2, invhz2, invhxyz2, by=by,
                           interpret=interpret)
 
@@ -39,12 +39,15 @@ _k.add_backend("xla", laplacian_xla)
 _k.add_backend("pallas", laplacian_pallas, available=on_tpu)
 _k.add_backend("pallas_interpret",
                functools.partial(laplacian_pallas, interpret=True))
-# y-slab height: the VMEM working set is 6*by*nx*itemsize, so the grid must
-# tile ny exactly — the autotuner sweeps the heights that do.
+# y-tile height: the tiles must cover ny exactly and the rolling window
+# (K.vmem_working_set_bytes) fit the VMEM budget — the autotuner sweeps the
+# heights that do; by=None (the whole plane where it fits) is the default.
 _k.declare_tunables(
     ("pallas", "pallas_interpret"),
     by=K.BY_GRID,
-    constraint=lambda p, u, *a, **kw: u.shape[1] % p["by"] == 0)
+    constraint=lambda p, u, *a, **kw: u.shape[1] % p["by"] == 0 and
+    K.vmem_working_set_bytes(u.shape, u.dtype.itemsize, p["by"])
+    <= K.VMEM_BUDGET)
 # AI ~= 13/24 flop/byte at fp32: memory-bound on every chip ridge the
 # auditor models (cpu-host 16.7 through H100 ~295)
 _k.declare_roofline_contract(("xla", "pallas", "pallas_interpret"),

@@ -22,6 +22,8 @@ def default_coefficients(hx: float = 1.0, hy: float = 1.0, hz: float = 1.0):
 def laplacian(u: jnp.ndarray, invhx2: float, invhy2: float, invhz2: float,
               invhxyz2: float) -> jnp.ndarray:
     c = u.dtype.type
+    if min(u.shape) < 2:   # one cell thick: all boundary, and the pad below
+        return jnp.zeros_like(u)  # would make that axis two cells long
     core = (u[1:-1, 1:-1, 1:-1] * c(invhxyz2)
             + (u[1:-1, 1:-1, :-2] + u[1:-1, 1:-1, 2:]) * c(invhx2)
             + (u[1:-1, :-2, 1:-1] + u[1:-1, 2:, 1:-1]) * c(invhy2)

@@ -64,6 +64,12 @@ def test_stencil7_l512(one_chip):
     _compile(one_chip, s7_ops.laplacian_pallas, [((512, 512, 512), F32)])
 
 
+def test_stencil7_l1024(one_chip):
+    """The paper's other size: 4 MiB planes overflow the VMEM budget, so
+    the default takes 512-row tiles with their halo copies."""
+    _compile(one_chip, s7_ops.laplacian_pallas, [((1024, 1024, 1024), F32)])
+
+
 @pytest.mark.parametrize("fn", [stream_ops.triad_pallas,
                                 stream_ops.dot_pallas],
                          ids=["triad", "dot"])
