@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import telemetry as tel
+
 # Rows per VMEM tile. 512x128 f32 = 256 KiB/operand — comfortably inside
 # VMEM next to double-buffering, and a multiple of the (8,128) vreg.
 BLOCK_ROWS = 512
@@ -56,11 +58,20 @@ def _tile(i):
     return (i, 0)
 
 
-def _elementwise_call(body, n, dtype, n_in, block_rows, interpret):
+def _traced_grid(op: str, n: int, block_rows: int) -> int:
+    """Grid steps of ``op`` over ``n`` elements, counted at trace time as
+    ``babelstream.tile.<op>.<block_rows>`` (the value adds the steps)."""
+    steps = _grid_1d(n, block_rows)
+    tel.counter(f"babelstream.tile.{op}.{block_rows}", steps,
+                proc="dispatch")
+    return steps
+
+
+def _elementwise_call(op, body, n, dtype, n_in, block_rows, interpret):
     spec = pl.BlockSpec((block_rows, LANES), _tile)
     return pl.pallas_call(
         body,
-        grid=(_grid_1d(n, block_rows),),
+        grid=(_traced_grid(op, n, block_rows),),
         in_specs=[spec] * n_in,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), dtype),
@@ -98,7 +109,7 @@ def _dot_body(a_ref, b_ref, o_ref, *, acc_dtype):
 # ---- pallas_call wrappers (operate on (n//128, 128) views) ---------------
 def copy_2d(a2, *, block_rows: int = BLOCK_ROWS, interpret: bool = False):
     n = a2.size
-    return _elementwise_call(_copy_body, n, a2.dtype, 1, block_rows,
+    return _elementwise_call("copy", _copy_body, n, a2.dtype, 1, block_rows,
                              interpret)(a2)
 
 
@@ -107,12 +118,13 @@ def mul_2d(c2, scalar, *, block_rows: int = BLOCK_ROWS,
     # `scalar` is a compile-time constant — the Mojo `alias` analogue.
     n = c2.size
     body = functools.partial(_mul_body, float(scalar))
-    return _elementwise_call(body, n, c2.dtype, 1, block_rows, interpret)(c2)
+    return _elementwise_call("mul", body, n, c2.dtype, 1, block_rows,
+                             interpret)(c2)
 
 
 def add_2d(a2, b2, *, block_rows: int = BLOCK_ROWS, interpret: bool = False):
     n = a2.size
-    return _elementwise_call(_add_body, n, a2.dtype, 2, block_rows,
+    return _elementwise_call("add", _add_body, n, a2.dtype, 2, block_rows,
                              interpret)(a2, b2)
 
 
@@ -120,7 +132,8 @@ def triad_2d(b2, c2, scalar, *, block_rows: int = BLOCK_ROWS,
              interpret: bool = False):
     n = b2.size
     body = functools.partial(_triad_body, float(scalar))
-    return _elementwise_call(body, n, b2.dtype, 2, block_rows, interpret)(b2, c2)
+    return _elementwise_call("triad", body, n, b2.dtype, 2, block_rows,
+                             interpret)(b2, c2)
 
 
 def dot_2d(a2, b2, *, block_rows: int = BLOCK_ROWS, interpret: bool = False):
@@ -130,7 +143,7 @@ def dot_2d(a2, b2, *, block_rows: int = BLOCK_ROWS, interpret: bool = False):
     in_spec = pl.BlockSpec((block_rows, LANES), _tile)
     out = pl.pallas_call(
         functools.partial(_dot_body, acc_dtype=acc_dtype),
-        grid=(_grid_1d(n, block_rows),),
+        grid=(_traced_grid("dot", n, block_rows),),
         in_specs=[in_spec, in_spec],
         # every grid step maps to the SAME (1,1) output block -> accumulator
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),

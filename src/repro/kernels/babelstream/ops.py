@@ -31,17 +31,18 @@ def _flat(x2):
     return x2.reshape(-1)
 
 
-def _make_elementwise(pallas_fn, n_in):
-    @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-    def run(*arrays, interpret=False, block_rows=K.BLOCK_ROWS):
-        arrs2 = [_as2d(a) for a in arrays]
-        return _flat(pallas_fn(*arrs2, interpret=interpret,
-                               block_rows=block_rows))
-    return run
+# One jitted wrapper per op, each under its own name: the trace names a
+# Pallas kernel after the jitted function around it.
+@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
+def copy_pallas(a, *, interpret=False, block_rows=K.BLOCK_ROWS):
+    return _flat(K.copy_2d(_as2d(a), interpret=interpret,
+                           block_rows=block_rows))
 
 
-copy_pallas = _make_elementwise(K.copy_2d, 1)
-add_pallas = _make_elementwise(K.add_2d, 2)
+@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
+def add_pallas(a, b, *, interpret=False, block_rows=K.BLOCK_ROWS):
+    return _flat(K.add_2d(_as2d(a), _as2d(b), interpret=interpret,
+                          block_rows=block_rows))
 
 
 @functools.partial(jax.jit,

@@ -55,6 +55,7 @@ def _compile(sharding, fn, shapes, **static):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = fn.lower(*args, **static).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
@@ -70,11 +71,15 @@ def test_stencil7_l1024(one_chip):
     _compile(one_chip, s7_ops.laplacian_pallas, [((1024, 1024, 1024), F32)])
 
 
-@pytest.mark.parametrize("fn", [stream_ops.triad_pallas,
-                                stream_ops.dot_pallas],
-                         ids=["triad", "dot"])
-def test_babelstream_2pow25(one_chip, fn):
-    _compile(one_chip, fn, [((1 << 25,), F32)] * 2)
+@pytest.mark.parametrize("op,n_in", [("copy", 1), ("mul", 1), ("add", 2),
+                                     ("triad", 2), ("dot", 2)],
+                         ids=["copy", "mul", "add", "triad", "dot"])
+def test_babelstream_2pow25(one_chip, op, n_in):
+    """Each kernel of the upstream iteration, named in the compiled module
+    after its own wrapper, as the device trace names it."""
+    fn = getattr(stream_ops, f"{op}_pallas")
+    text = _compile(one_chip, fn, [((1 << 25,), F32)] * n_in)
+    assert f"%{op}_pallas" in text
 
 
 def test_minibude_bm1(one_chip):
