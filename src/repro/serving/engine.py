@@ -65,6 +65,12 @@ collects, on the trace's ``/host:CPU`` plane beside the device's programs::
         serving.decode.wait        np.asarray(toks), blocking on the device
         serving.decode.emit        per-slot token loop and any finish
 
+Both programs take the KV cache over (``donate_argnames``): XLA writes the
+new cache into the old one's buffers, and the engine keeps only the
+returned cache.  After each prefill and decode call the counter
+``serving.cache.donated`` counts a cache taken over, and
+``serving.cache.kept`` one the program left alive beside its copy.
+
 ``serving.decode_step`` carries ``step``, ``active`` and ``queued``.  Every
 span opens and closes in Python around the compiled programs, never inside
 them: telemetry changes no compiled shape and no sampled token.
@@ -109,6 +115,15 @@ def scatter_slot_cache(big, small, slot):
         "segments": [jax.tree.map(upd(1), bg, sm)
                      for bg, sm in zip(big["segments"], small["segments"])],
     }
+
+
+def _count_donation(old) -> None:
+    """Count whether the program just called took the cache ``old`` over
+    (``serving.cache.donated``) or left it alive beside its new copy
+    (``serving.cache.kept``)."""
+    kept = not jax.tree.leaves(old)[0].is_deleted()
+    tel.counter("serving.cache.kept" if kept else "serving.cache.donated",
+                proc="engine")
 
 
 class JetThread(threading.Thread):
@@ -244,8 +259,10 @@ class ServingEngine:
                                              caches)
                 return sample_per_slot(logits, keys, temp), caches
 
-        self._prefill = jax.jit(prefill_fn)
-        self._decode = jax.jit(decode_fn)
+        # the engine never reads a cache it has passed in again, so both
+        # programs update it in place instead of copying it whole
+        self._prefill = jax.jit(prefill_fn, donate_argnames=("caches",))
+        self._decode = jax.jit(decode_fn, donate_argnames=("caches",))
 
     def _clock(self) -> float:
         return time.perf_counter() - self._t0
@@ -342,6 +359,7 @@ class ServingEngine:
                 self.block_tables[slot] = row
             with tel.span("serving.prefill", proc="engine", uid=req.uid,
                           slot=slot, prompt_len=L, bucket=bucket):
+                old = self.caches
                 if self.cache_layout == "paged":
                     tok0, self.caches = self._prefill(
                         self.params, jnp.asarray(toks),
@@ -352,6 +370,7 @@ class ServingEngine:
                         self.params, jnp.asarray(toks),
                         jnp.asarray([L], jnp.int32), np.int32(slot), sub,
                         self.caches)
+                _count_donation(old)
                 with tel.span("serving.prefill.wait", proc="engine"):
                     tok0 = int(tok0)     # device sync
             self.stats["prefill_calls"] += 1
@@ -387,6 +406,7 @@ class ServingEngine:
                         if req is not None:
                             req.key, sub = jax.random.split(req.key)
                             keys[s] = np.asarray(sub)
+                old = self.caches
                 if self.cache_layout == "paged":
                     toks, self.caches = self._decode(
                         self.params, jnp.asarray(self.tok_buf),
@@ -397,6 +417,7 @@ class ServingEngine:
                         self.params, jnp.asarray(self.tok_buf),
                         jnp.asarray(self.pos_buf), jnp.asarray(keys),
                         self.caches)
+                _count_donation(old)
             with tel.span("serving.decode.wait", proc="engine"):
                 toks = np.asarray(toks)      # device sync
             self.stats["decode_steps"] += 1

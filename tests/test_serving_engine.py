@@ -1,8 +1,9 @@
 """Continuous-batching engine tests: padded-prefill correctness, greedy
 equivalence with unbatched decode (both cache layouts, both driver loops),
-fixed-shape/bounded-compile contracts, paged-pool admission gating, and the
-slot/block/queue plumbing."""
+the cache donated to both programs, fixed-shape/bounded-compile contracts,
+paged-pool admission gating, and the slot/block/queue plumbing."""
 
+import os
 import subprocess
 import sys
 
@@ -12,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core import telemetry as tel
 from repro.models import transformer as T
 from repro.serving import (BlockAllocator, Request, RequestQueue,
                            ServingEngine, SlotAllocator)
@@ -201,6 +203,37 @@ def test_paged_engine_matches_unbatched_and_contiguous(params):
     # every page returned, every table row parked on the trash page
     assert eng.balloc.available() == eng.balloc.capacity()
     assert np.all(eng.block_tables == TRASH_BLOCK)
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_engine_donates_its_cache(params, layout):
+    """Both programs take the KV cache over: after a step the cache the
+    engine held before it is deleted, every prefill and decode call counts
+    ``serving.cache.donated`` and none ``serving.cache.kept``, and greedy
+    tokens still match unbatched decode."""
+    rec = tel.configure("on")
+    try:
+        eng = ServingEngine(params, CFG, num_slots=2, cache_len=48,
+                            prefill_len=16, cache_layout=layout,
+                            block_size=8)
+        reqs = _requests([3, 9, 12, 5, 7], max_new=6)
+        for r in reqs:
+            eng.submit(r)
+        held = jax.tree.leaves(eng.caches)
+        eng.step()
+        assert all(a.is_deleted() for a in held)
+        assert not any(a.is_deleted() for a in jax.tree.leaves(eng.caches))
+        while eng.active_count() or len(eng.queue):
+            eng.step()
+        counters = rec.snapshot()["counters"]
+    finally:
+        tel.configure(os.environ.get(tel.ENV))
+    calls = eng.stats["prefill_calls"] + eng.stats["decode_steps"]
+    assert eng.stats["prefill_calls"] == 5 and calls > 5
+    assert counters.get("serving.cache.donated") == calls
+    assert "serving.cache.kept" not in counters
+    for r in reqs:
+        assert list(r.generated) == _oracle_tokens(params, r, 48)
 
 
 def test_paged_pool_admission_gating(params):

@@ -6,7 +6,8 @@ chip of a described ``v5e:2x2`` topology.  Nothing runs: a pass proves the
 chip's compiler accepts the kernel — the refusals interpret mode cannot see
 (unaligned blocks, unlowerable primitives, VMEM overuse) fail here.  Every
 compile must contain the Mosaic kernel (``tpu_custom_call``); an XLA
-fallback would not.
+fallback would not.  The serving engine's own programs are compiled the
+same way, to show that they update the KV cache in place.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU library.
@@ -14,6 +15,7 @@ the worker that runs this file loads the TPU library.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -21,11 +23,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.babelstream import ops as stream_ops
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.hartree_fock import ops as hf_ops
 from repro.kernels.minibude import ops as mb_ops
 from repro.kernels.stencil7 import ops as s7_ops
+from repro.models import transformer as T
+from repro.serving import engine as E
 
 # granite-3-8b attention widths
 H, KV, DH = 32, 8, 128
@@ -107,3 +112,57 @@ def test_decode_granite_four_slots(one_chip):
              [((SLOTS, 1, H, DH), BF16), ((SLOTS, CACHE_LEN, KV, DH), BF16),
               ((SLOTS, CACHE_LEN, KV, DH), BF16), ((SLOTS, 1), I32),
               ((SLOTS, CACHE_LEN), I32)])
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_engine_programs_update_the_cache_in_place(one_chip, monkeypatch,
+                                                   program, layout):
+    """The serving engine's own programs at the granite cell's slots and
+    cache length (two layers: the copy shows at any depth) take the KV
+    cache over: the whole cache is aliased to the output, and no K or V
+    leaf is copied whole into a fresh buffer."""
+    slots, cache_len, bucket = 24, 2048, 128
+    cfg = dataclasses.replace(get_config("granite-3-8b"), n_layers=2,
+                              param_dtype="bfloat16")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    with monkeypatch.context() as m:       # the engine's cache, shapes only
+        for name in ("init_caches", "init_paged_caches"):
+            made = getattr(E, name)
+            m.setattr(E, name, lambda *a, made=made, **k:
+                      jax.eval_shape(lambda: made(*a, **k)))
+        eng = E.ServingEngine(params, cfg, num_slots=slots,
+                              cache_len=cache_len, prefill_len=bucket,
+                              cache_layout=layout)
+    caches = on_chip(eng.caches)
+    table = ([arg((slots, eng.pages_per_slot), I32)]
+             if layout == "paged" else [])
+    if program == "prefill":
+        row = [arg((eng.pages_per_slot,), I32)] if table else []
+        args = [on_chip(params), arg((1, bucket), I32), arg((1,), I32),
+                *row, arg((), I32), arg((2,), jnp.uint32), caches]
+        fn = eng._prefill
+    else:
+        args = [on_chip(params), arg((slots, 1), I32), arg((slots, 1), I32),
+                arg((slots, 2), jnp.uint32), caches, *table]
+        fn = eng._decode
+    compiled = fn.lower(*args).compile()
+
+    leaves = jax.tree.leaves(caches)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    text = compiled.as_text()
+    for a in leaves:
+        if a.dtype == BF16:                # K and V: all but 0.1 % of it
+            whole = f"bf16[{','.join(map(str, a.shape))}]"
+            copies = [ln for ln in text.splitlines()
+                      if f"= {whole}" in ln and " copy(" in ln]
+            assert not copies, copies
