@@ -14,8 +14,6 @@ Emits ``name,us_per_call,derived`` CSV (scaffold contract).  Mapping:
                         (simulated host devices; writes BENCH_scaling.json)
     roofline_kernels -> paper Fig. 2 + Tables 2-3 (AI / bound placement)
     lm_step          -> framework-level LM step timings
-    serving          -> continuous-batching engine tok/s + p50/p95 latency
-                        under a Poisson-ish synthetic arrival trace
     analysis         -> registry-wide static kernel auditor (jaxpr/grid/
                         collective/recompile passes; writes
                         BENCH_analysis.json, fails on non-waived findings)
@@ -41,7 +39,7 @@ from repro.launch.compile_cache import configure_compile_cache
 
 MODULES = ["stencil", "babelstream", "minibude", "hartree_fock",
            "portability", "scaling", "roofline_kernels", "lm_step",
-           "serving", "analysis"]
+           "analysis"]
 
 
 def _run_module(name: str, smoke: bool) -> None:

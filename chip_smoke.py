@@ -13,10 +13,10 @@ One chip runs these phases in order, in one process, catching nothing:
    lattice.
 2. A ``ServingEngine`` over granite-3-8b at its published widths, depth cut
    to fit one chip, random weights from a seed, ``attn_backend="pallas"``.
-   It serves the same requests under the contiguous and the paged KV
-   layout, asserts that the dispatch log shows ``pallas`` prefill and
-   decode with no fallback, asserts the engine's compile bound, and checks
-   greedy tokens against the unbatched ``serve_step.generate`` loop.
+   It serves the requests once through ``ServingEngine.run``, asserts that
+   the dispatch log shows ``pallas`` prefill and decode with no fallback,
+   asserts the engine's compile bound, and checks greedy tokens against
+   the unbatched ``serve_step.generate`` loop.
 
 ``--four-chips`` runs only the sharded science path and the single-device
 results it is compared with: stencil7 at L=512 in slab and pencil
@@ -162,17 +162,15 @@ def make_requests(vocab: int, n: int, max_prompt: int, max_new: int):
             for i in range(n)]
 
 
-def serve_once(params, cfg, backend: str, layout: str, *, slots: int,
-               cache_len: int, buckets, requests):
+def serve_once(params, cfg, backend: str, *, slots: int, cache_len: int,
+               buckets, requests):
     """Serve ``requests`` on a fresh engine; check dispatch and compiles.
     Returns {uid: generated tokens}."""
     A.reset_dispatch_log()
     engine = ServingEngine(params, cfg, num_slots=slots, cache_len=cache_len,
-                           prefill_buckets=buckets, attn_backend=backend,
-                           cache_layout=layout)
+                           prefill_buckets=buckets, attn_backend=backend)
     t0 = time.perf_counter()
-    done = engine.run([dataclasses.replace(r, generated=[], t_tokens=[])
-                       for r in requests])
+    done = engine.run(requests)
     dt = time.perf_counter() - t0
     s = engine.stats
     check(len(done) == len(requests), f"{len(done)} of {len(requests)} done")
@@ -185,7 +183,7 @@ def serve_once(params, cfg, backend: str, layout: str, *, slots: int,
         check(rec["backend"] == backend and "fallback" not in rec, rec)
     check(s["decode_traces"] == 1, s)
     check(s["prefill_traces"] <= len(engine.prefill_buckets), s)
-    log(f"  serve [{layout}] {len(done)} requests, {s['tokens_generated']} "
+    log(f"  serve {len(done)} requests, {s['tokens_generated']} "
         f"tokens, {slots} slots, cache_len {cache_len}, buckets "
         f"{list(engine.prefill_buckets)}: {dt:.3f} s (compile included); "
         f"dispatch prefill={engine.attn_backends['prefill']} "
@@ -237,15 +235,12 @@ def serving_phase(backend: str, *, layers: int, slots: int, cache_len: int,
                              max_new)
     log(f"  requests: {n_requests}, prompt lengths "
         f"{[r.prompt_len for r in requests]}, {max_new} new tokens each")
-    kw = dict(slots=slots, cache_len=cache_len, buckets=buckets,
-              requests=requests)
-    contiguous = serve_once(params, cfg, backend, "contiguous", **kw)
-    paged = serve_once(params, cfg, backend, "paged", **kw)
-    check(paged == contiguous, "paged layout changed a token")
-    log("  paged tokens == contiguous tokens")
+    served = serve_once(params, cfg, backend, slots=slots,
+                        cache_len=cache_len, buckets=buckets,
+                        requests=requests)
     engine_cfg = dataclasses.replace(cfg, attn_backend=backend)
     for req in requests[:2]:
-        greedy_check(params, engine_cfg, backend, req, contiguous[req.uid],
+        greedy_check(params, engine_cfg, backend, req, served[req.uid],
                      cache_len)
     log(f"phase serving: ok in {time.perf_counter() - t0:.3f} s, "
         f"peak_bytes_in_use {peak_bytes()}")

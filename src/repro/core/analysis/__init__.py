@@ -81,15 +81,9 @@ MAX_TUNABLE_POINTS = 32
 
 def audit_pairs(smoke: bool = False) -> List[Tuple[str, str]]:
     """The audited (kernel, backend) matrix — conformance_pairs(), whole or
-    filtered to the smoke kernels.  Derived from the live registry.
-
-    Kernels registered with ``jaxpr_traceable=False`` (host-side driver
-    loops like ``serving.engine``) are excluded: they have no single jaxpr
-    to audit — conformance still executes them."""
+    filtered to the smoke kernels.  Derived from the live registry."""
     from repro.core import conformance
-    from repro.core.portable import registry
-    pairs = [(k, b) for k, b in conformance.conformance_pairs()
-             if registry.get(k).jaxpr_traceable]
+    pairs = conformance.conformance_pairs()
     if smoke:
         pairs = [(k, b) for k, b in pairs if k in SMOKE_KERNELS]
     return pairs

@@ -106,14 +106,6 @@ def _wkv_case():
     return (rr, kk, vv, lw, u), {}
 
 
-def _serving_case():
-    """The serving engine's end-to-end token-stream case (see
-    ``repro.serving.portable``): args are (params, cfg); every engine
-    backend rebuilds its own deterministic trace internally."""
-    from repro.serving import portable as serving_portable
-    return serving_portable.case_args(), {}
-
-
 CASES: Dict[str, Callable[[], Tuple[tuple, dict]]] = {
     "stencil7": _stencil_case,
     "babelstream.copy": lambda: _stream_case(1),
@@ -126,7 +118,6 @@ CASES: Dict[str, Callable[[], Tuple[tuple, dict]]] = {
     "attention.flash": _flash_case,
     "attention.decode": _decode_case,
     "rwkv6.wkv": _wkv_case,
-    "serving.engine": _serving_case,
 }
 
 #: per-kernel default tolerance vs the oracle (from the families' own
@@ -143,9 +134,6 @@ ORACLE_TOL: Dict[str, Tolerance] = {
     "attention.flash": (2e-4, 2e-4),
     "attention.decode": (2e-4, 2e-4),
     "rwkv6.wkv": (3e-4, 3e-4),
-    # continuous batching, cache layout (contiguous vs paged), and driver
-    # threading are scheduling concerns — they may never change a token
-    "serving.engine": "bitwise",
 }
 
 #: (kernel, backend) overrides — bitwise where PR 3/4 promised it: the

@@ -150,10 +150,6 @@ class PortableKernel:
     #: ``declare_roofline_contract``); audited by ``analysis.cost``
     roofline_contracts: Dict[str, Dict[str, Any]] = dataclasses.field(
         default_factory=dict)
-    #: False for kernels whose backends are host-side driver loops (e.g. the
-    #: serving engine) rather than pure jax functions — the static auditor
-    #: and jaxpr-based passes skip them; conformance still runs them.
-    jaxpr_traceable: bool = True
 
     # ---- registration -------------------------------------------------
     def add_backend(self, name: str, fn: Callable[..., Any],
@@ -452,14 +448,13 @@ registry = KernelRegistry()
 def register_kernel(name: str, *, oracle: str = "xla",
                     flops_model: Optional[Callable[..., float]] = None,
                     bytes_model: Optional[Callable[..., float]] = None,
-                    doc: str = "",
-                    jaxpr_traceable: bool = True) -> PortableKernel:
+                    doc: str = "") -> PortableKernel:
     """Create-or-get a PortableKernel in the global registry."""
     if name in registry:
         return registry.get(name)
     return registry.register(PortableKernel(
         name=name, oracle=oracle, flops_model=flops_model,
-        bytes_model=bytes_model, doc=doc, jaxpr_traceable=jaxpr_traceable))
+        bytes_model=bytes_model, doc=doc))
 
 
 def get_kernel(name: str) -> PortableKernel:

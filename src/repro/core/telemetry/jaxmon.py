@@ -3,8 +3,7 @@
 PR 7's static auditor proves *statically* that no backend builder bakes
 scalars into its compile key; this is the runtime twin — a recompile storm
 (the fixed-shape serving engine retracing mid-benchmark, a tuning sweep
-recompiling per point) becomes a visible counter in every trace and in the
-serving artifact's ``jax_compile_events`` column.
+recompiling per point) becomes a visible counter in every trace.
 
 jax 0.4.37 reports compilation through ``jax.monitoring`` duration events:
 
@@ -21,8 +20,7 @@ makes it a cheap no-op and re-enabling picks the new recorder up without
 re-registration.  Counter names are the jax event path with ``/`` -> ``.``
 (``jax.core.compile.jaxpr_trace_duration``); the backend compile
 additionally lands as a ``jax.compile`` span so summarize reports
-compile-time percentiles, and as the :data:`COMPILE_COUNTER` aggregate the
-serving artifact reports.
+compile-time percentiles, and as the :data:`COMPILE_COUNTER` aggregate.
 """
 
 from __future__ import annotations
@@ -34,7 +32,7 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 
 #: aggregated-counter name for backend compiles (the "recompile storm"
-#: runtime metric reported in BENCH_serving.json)
+#: runtime metric)
 COMPILE_COUNTER = "jax.compile.backend_compile"
 
 _installed = False

@@ -17,7 +17,3 @@ import repro.kernels.rwkv6.ops  # noqa: F401
 # tile x shard tunable spaces
 import repro.distributed.domain  # noqa: F401
 import repro.distributed.shard_pallas  # noqa: F401
-
-# host-side driver-loop "kernel": the serving engine's token-stream
-# conformance entry (jaxpr_traceable=False — static passes skip it)
-import repro.serving.portable  # noqa: F401

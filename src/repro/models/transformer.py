@@ -423,29 +423,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     return logits, new_caches, aux
 
 
-def cache_seq_lens(cfg: ModelConfig, seq_len: int) -> Dict[str, Any]:
-    """Per-plan-entry KV sequence lengths of ``init_caches(cfg, _, seq_len)``.
-
-    Mirrors the layer plan: ``{"eager": {id: len}, "segments": [len]}``.
-    A sliding-window layer's ring buffer is ``min(window, seq_len)`` long;
-    everything else stores the full ``seq_len``.  The paged serving layout
-    (``repro.serving.paged``) uses this to decide which cache entries page
-    into the shared block pool (full-length) and which stay per-slot
-    (bounded rings shorter than ``seq_len``).
-    """
-    def one(idx: int) -> int:
-        kind = layer_kind(cfg, idx)
-        return min(kind["window"], seq_len) if kind["window"] else seq_len
-
-    out: Dict[str, Any] = {"eager": {}, "segments": []}
-    for tag, arg in layer_plan(cfg):
-        if tag == "eager":
-            out["eager"][str(arg)] = one(arg)
-        else:
-            out["segments"].append(one(arg[0]))  # homogeneous segment
-    return out
-
-
 def init_caches(cfg: ModelConfig, batch: int, seq_len: int) -> Params:
     """Decode caches per the layer plan (ring buffers for SWA layers)."""
     cdt = cfg.cdtype()

@@ -1,23 +1,11 @@
-"""Continuous-batching serving engine (fixed shapes, slot-granular).
+"""Continuous-batching serving engine (fixed shapes, slot-granular): one
+contiguous KV-cache row per slot, a prefill bucket ladder, and one serving
+loop (``ServingEngine.submit``/``step``, with ``run`` a loop over them)."""
 
-Engine v2 adds a paged KV-cache layout (``cache_layout="paged"``: block
-pool + per-slot block tables, see ``paged``/``slots``), a prefill bucket
-ladder, and a threaded producer/consumer driver loop
-(``ServingEngine.run_threaded``).
-"""
-
-from repro.serving.engine import JetThread, ServingEngine, scatter_slot_cache
-from repro.serving.paged import (check_paged_geometry, gather_caches,
-                                 init_paged_caches, scatter_decode,
-                                 scatter_prefill)
+from repro.serving.engine import ServingEngine, scatter_slot_cache
 from repro.serving.request import Request, RequestQueue
-from repro.serving.slots import (RESERVED_BLOCKS, SENTINEL_BLOCK, TRASH_BLOCK,
-                                 BlockAllocator, SlotAllocator)
+from repro.serving.slots import SlotAllocator
 from repro.serving.trace import latency_summary, synthetic_trace
 
-__all__ = ["ServingEngine", "JetThread", "scatter_slot_cache", "Request",
-           "RequestQueue", "SlotAllocator", "BlockAllocator",
-           "SENTINEL_BLOCK", "TRASH_BLOCK", "RESERVED_BLOCKS",
-           "check_paged_geometry", "init_paged_caches", "gather_caches",
-           "scatter_prefill", "scatter_decode", "latency_summary",
-           "synthetic_trace"]
+__all__ = ["ServingEngine", "scatter_slot_cache", "Request", "RequestQueue",
+           "SlotAllocator", "latency_summary", "synthetic_trace"]

@@ -70,14 +70,6 @@ class RequestQueue:
     def has_ready(self, now: float) -> bool:
         return bool(self._q) and self._q[0].arrival_time <= now
 
-    def peek_ready(self, now: float) -> Optional[Request]:
-        """The request the scheduler would admit next, without popping —
-        admission gates (free slot AND, when paged, enough free KV pages
-        for *this* request) inspect it first."""
-        if self.has_ready(now):
-            return self._q[0]
-        return None
-
     def pop_ready(self, now: float) -> Optional[Request]:
         if self.has_ready(now):
             return self._q.popleft()

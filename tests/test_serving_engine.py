@@ -1,8 +1,10 @@
 """Continuous-batching engine tests: padded-prefill correctness, greedy
-equivalence with unbatched decode (both cache layouts, both driver loops),
-the cache donated to both programs, fixed-shape/bounded-compile contracts,
-paged-pool admission gating, and the slot/block/queue plumbing."""
+equivalence with unbatched decode over several engine geometries, the cache
+donated to both programs, fixed-shape/bounded-compile contracts, ``run`` as
+a loop over ``submit``/``step``, FIFO admission, request and geometry
+validation, and the slot/queue plumbing."""
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -15,9 +17,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import telemetry as tel
 from repro.models import transformer as T
-from repro.serving import (BlockAllocator, Request, RequestQueue,
-                           ServingEngine, SlotAllocator)
-from repro.serving.slots import RESERVED_BLOCKS, TRASH_BLOCK
+from repro.serving import Request, RequestQueue, ServingEngine, SlotAllocator
 from repro.serving.trace import latency_summary, synthetic_trace
 from repro.training import serve_step as SS
 
@@ -72,7 +72,6 @@ def test_padded_prefill_matches_unpadded(params):
 def test_padded_prefill_sliding_window_pads_dropped(params):
     """Pads must not clobber ring-buffer slots when the window is shorter
     than the padded length (pos -1 would alias slot window-1)."""
-    import dataclasses
     wcfg = dataclasses.replace(CFG, window=8)
     wparams = T.init_params(wcfg, jax.random.PRNGKey(5))
     rng = np.random.default_rng(6)
@@ -115,17 +114,40 @@ def test_padded_prefill_decode_positions_consistent(params):
 # --------------------------------------------------------------------------
 # engine: greedy equivalence + fixed-shape contract
 # --------------------------------------------------------------------------
-def test_engine_matches_unbatched_greedy(params):
+#: (engine keywords, prompt lengths, max_new_tokens, request rng seed,
+#: config changes) — each a geometry the engine must serve bit-exactly
+GEOMETRIES = {
+    "two-slots": (dict(num_slots=2, cache_len=48, prefill_len=16),
+                  [3, 9, 12, 5, 7], 6, 0, {}),
+    "one-slot": (dict(num_slots=1, cache_len=48, prefill_len=16),
+                 [3, 9, 12, 5, 7], 6, 0, {}),
+    # six requests through two slots over two prefill buckets
+    "conformance-trace": (dict(num_slots=2, cache_len=32,
+                               prefill_buckets=(8, 16)),
+                          [3, 9, 12, 5, 16, 1], 4, 42, {}),
+    "four-slots-ladder": (dict(num_slots=4, cache_len=48,
+                               prefill_buckets=(4, 8, 16)),
+                          [3, 4, 7, 2, 12, 16, 9], 6, 1, {}),
+    # every layer a ring of 8 positions, shorter than most prompts
+    "window": (dict(num_slots=2, cache_len=48, prefill_len=16),
+               [3, 9, 12, 5, 7], 6, 0, {"window": 8}),
+}
+
+
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_engine_matches_unbatched_greedy(params, geometry):
     """Ragged prompts through slot recycling == per-request unbatched
-    greedy decode, token for token."""
-    reqs = _requests([3, 9, 12, 5, 7], max_new=6)
-    eng = ServingEngine(params, CFG, num_slots=2, cache_len=48,
-                        prefill_len=16)
+    greedy decode, token for token (bitwise: continuous batching is a
+    scheduling concern and may never change a sampled token)."""
+    kw, lens, max_new, seed, changes = GEOMETRIES[geometry]
+    cfg = dataclasses.replace(CFG, **changes)
+    reqs = _requests(lens, max_new=max_new, rng_seed=seed)
+    eng = ServingEngine(params, cfg, **kw)
     done = eng.run(reqs)
-    assert len(done) == 5
+    assert len(done) == len(lens)
     for r in done:
-        ref = SS.generate(params, CFG, jnp.asarray(r.prompt)[None],
-                          max_new_tokens=6, cache_len=48)
+        ref = SS.generate(params, cfg, jnp.asarray(r.prompt)[None],
+                          max_new_tokens=max_new, cache_len=kw["cache_len"])
         np.testing.assert_array_equal(np.asarray(r.generated),
                                       np.asarray(ref[0]))
 
@@ -159,15 +181,26 @@ def test_engine_sampled_continuations_differ(params):
     assert len(gens) > 1, "all requests sampled the same continuation"
 
 
-def test_engine_rejects_oversized(params):
+@pytest.mark.parametrize("prompt_len,max_new", [(0, 4), (9, 4), (8, 32)],
+                         ids=["empty", "over-bucket", "over-cache_len"])
+def test_engine_rejects_invalid_requests(params, prompt_len, max_new):
     eng = ServingEngine(params, CFG, num_slots=2, cache_len=32,
                         prefill_len=8)
     with pytest.raises(ValueError):
-        eng.submit(Request(uid=0, prompt=np.arange(9, dtype=np.int32),
-                           max_new_tokens=4))
+        eng.submit(Request(uid=0, prompt=np.arange(prompt_len,
+                                                   dtype=np.int32),
+                           max_new_tokens=max_new))
+    assert not eng.queue
+
+
+@pytest.mark.parametrize("kw", [
+    dict(prefill_buckets=(0, 8)), dict(prefill_buckets=(8, 64)),
+    dict(prefill_buckets=()), dict(cache_layout="paged")],
+    ids=["bucket-0", "bucket-over-cache_len", "empty-ladder",
+         "paged-layout"])
+def test_engine_rejects_bad_geometry(params, kw):
     with pytest.raises(ValueError):
-        eng.submit(Request(uid=1, prompt=np.arange(8, dtype=np.int32),
-                           max_new_tokens=32))
+        ServingEngine(params, CFG, num_slots=2, cache_len=32, **kw)
 
 
 def test_engine_rejects_stateful_archs(params):
@@ -177,7 +210,7 @@ def test_engine_rejects_stateful_archs(params):
 
 
 # --------------------------------------------------------------------------
-# engine v2: paged KV layout, bucket ladder, threaded loop, scheduler edges
+# bucket ladder, donation, the run loop, scheduler edges
 # --------------------------------------------------------------------------
 def _oracle_tokens(params, req, cache_len):
     ref = SS.generate(params, CFG, jnp.asarray(req.prompt)[None],
@@ -185,37 +218,19 @@ def _oracle_tokens(params, req, cache_len):
     return [int(t) for t in np.asarray(ref)[0]]
 
 
-def test_paged_engine_matches_unbatched_and_contiguous(params):
-    """The paged pool + block tables are pure layout: greedy tokens must
-    bit-match both the contiguous engine and unbatched decode."""
-    def serve(layout):
-        eng = ServingEngine(params, CFG, num_slots=2, cache_len=48,
-                            prefill_len=16, cache_layout=layout,
-                            block_size=8)
-        done = eng.run(_requests([3, 9, 12, 5, 16, 1], max_new=6))
-        return {r.uid: list(r.generated) for r in done}, eng
-
-    got_paged, eng = serve("paged")
-    got_contig, _ = serve("contiguous")
-    assert got_paged == got_contig
-    for r in _requests([3, 9, 12, 5, 16, 1], max_new=6):
-        assert got_paged[r.uid] == _oracle_tokens(params, r, 48)
-    # every page returned, every table row parked on the trash page
-    assert eng.balloc.available() == eng.balloc.capacity()
-    assert np.all(eng.block_tables == TRASH_BLOCK)
-
-
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
-def test_engine_donates_its_cache(params, layout):
+@pytest.mark.parametrize("temperature", [0.0, 1.0],
+                         ids=["greedy", "sampled"])
+def test_engine_donates_its_cache(params, temperature):
     """Both programs take the KV cache over: after a step the cache the
     engine held before it is deleted, every prefill and decode call counts
-    ``serving.cache.donated`` and none ``serving.cache.kept``, and greedy
-    tokens still match unbatched decode."""
+    ``serving.cache.donated`` and none ``serving.cache.kept``, and the
+    tokens still match: greedy ones unbatched decode, sampled ones (per-slot
+    key splits) a fresh engine serving the same trace."""
+    kw = dict(num_slots=2, cache_len=48, prefill_len=16,
+              temperature=temperature)
     rec = tel.configure("on")
     try:
-        eng = ServingEngine(params, CFG, num_slots=2, cache_len=48,
-                            prefill_len=16, cache_layout=layout,
-                            block_size=8)
+        eng = ServingEngine(params, CFG, **kw)
         reqs = _requests([3, 9, 12, 5, 7], max_new=6)
         for r in reqs:
             eng.submit(r)
@@ -232,33 +247,13 @@ def test_engine_donates_its_cache(params, layout):
     assert eng.stats["prefill_calls"] == 5 and calls > 5
     assert counters.get("serving.cache.donated") == calls
     assert "serving.cache.kept" not in counters
-    for r in reqs:
-        assert list(r.generated) == _oracle_tokens(params, r, 48)
-
-
-def test_paged_pool_admission_gating(params):
-    """A pool smaller than the slot count's worth of rows serializes
-    admissions on free pages (FIFO head-of-line) without changing tokens."""
-    # 6 pages of 8 = room for at most two of these requests' reservations
-    # (12 + 5 -> 2 + 1 pages, 9 + 5 -> 2 pages, ...), far below 4 slots
-    eng = ServingEngine(params, CFG, num_slots=4, cache_len=16,
-                        prefill_len=8, cache_layout="paged", block_size=8,
-                        num_blocks=RESERVED_BLOCKS + 2)
-    reqs = _requests([3, 8, 5, 2, 7], max_new=6)
-    done = eng.run(reqs)
-    assert len(done) == 5
-    for r in done:
-        assert list(r.generated) == _oracle_tokens(params, r, 16)
-    assert eng.balloc.available() == eng.balloc.capacity()
-
-
-def test_paged_request_larger_than_pool_rejected(params):
-    eng = ServingEngine(params, CFG, num_slots=2, cache_len=16,
-                        prefill_len=8, cache_layout="paged", block_size=2,
-                        num_blocks=RESERVED_BLOCKS + 3)   # 6 positions max
-    with pytest.raises(ValueError):
-        eng.submit(Request(uid=0, prompt=np.arange(2, 8, dtype=np.int32),
-                           max_new_tokens=4))             # needs 5 pages
+    if temperature == 0.0:
+        want = {r.uid: _oracle_tokens(params, r, 48) for r in reqs}
+    else:
+        again = ServingEngine(params, CFG, **kw).run(
+            _requests([3, 9, 12, 5, 7], max_new=6))
+        want = {r.uid: list(r.generated) for r in again}
+    assert {r.uid: list(r.generated) for r in reqs} == want
 
 
 def test_prefill_bucket_ladder_bounds_compiles(params):
@@ -280,54 +275,67 @@ def test_prefill_bucket_ladder_bounds_compiles(params):
         assert list(r.generated) == _oracle_tokens(params, r, 48)
 
 
-def test_threaded_loop_matches_sync(params):
-    """run_threaded (injector + admission threads, bounded backpressure
-    queue) produces bitwise the sync loop's greedy tokens."""
-    def serve(threaded):
-        eng = ServingEngine(params, CFG, num_slots=2, cache_len=48,
-                            prefill_buckets=(8, 16), cache_layout="paged",
-                            block_size=8)
-        reqs = _requests([3, 9, 12, 5, 7], max_new=6,
-                         arrivals=[0.0, 0.0, 0.01, 0.02, 0.03])
-        done = eng.run_threaded(reqs) if threaded else eng.run(reqs)
-        assert eng.stats["requests_finished"] == 5
-        return {r.uid: list(r.generated) for r in done}
+def test_run_is_a_loop_over_submit_and_step(params):
+    """``run(trace)`` and a hand-driven ``submit``/``step`` loop, as the
+    benchmark drives the engine, give equal tokens and equal stats."""
+    kw = dict(num_slots=2, cache_len=48, prefill_buckets=(8, 16))
+    ran = ServingEngine(params, CFG, **kw)
+    done = ran.run(_requests([3, 9, 12, 5, 7], max_new=6))
+    stepped = ServingEngine(params, CFG, **kw)
+    reqs = _requests([3, 9, 12, 5, 7], max_new=6)
+    for r in reqs:
+        stepped.submit(r)
+    fin = []
+    while stepped.active_count() or len(stepped.queue):
+        fin += stepped.step()
+    assert len(done) == len(fin) == 5
+    assert ({r.uid: list(r.generated) for r in done}
+            == {r.uid: list(r.generated) for r in fin})
+    assert ran.stats == stepped.stats
 
-    assert serve(threaded=True) == serve(threaded=False)
+
+def test_fifo_head_of_line(params):
+    """With one slot, requests are admitted (and so finish) in arrival
+    order, whatever their lengths."""
+    arrivals = [0.0, 0.0, 0.01, 0.02]
+    reqs = _requests([12, 3, 9, 2], max_new=3, arrivals=arrivals)
+    eng = ServingEngine(params, CFG, num_slots=1, cache_len=32,
+                        prefill_len=16)
+    done = eng.run(reqs)
+    assert [r.uid for r in done] == [0, 1, 2, 3]
+    admitted = [r.t_admitted for r in reqs]
+    assert admitted == sorted(admitted)
+    assert all(a.t_done <= b.t_admitted for a, b in zip(reqs, reqs[1:]))
 
 
-def test_threaded_vs_sync_subprocess(params):
-    """Tier-1 end-to-end check in a fresh interpreter: the threaded and
-    synchronous loops serve the same trace to bitwise-identical tokens."""
-    code = """
-import numpy as np, jax
-from repro.configs import get_config
-from repro.models.transformer import init_params
-from repro.serving import ServingEngine, Request
+def test_run_waits_for_future_arrivals(params):
+    """``run`` admits nothing before it is due: it idles until a request's
+    arrival time, and then serves it."""
+    reqs = _requests([3, 5, 4], max_new=2, arrivals=[0.0, 0.15, 0.3])
+    eng = ServingEngine(params, CFG, num_slots=2, cache_len=32,
+                        prefill_len=8)
+    done = eng.run(reqs)
+    assert len(done) == 3
+    for r in reqs:
+        assert r.t_admitted >= r.arrival_time
+        assert r.t_first_token >= r.t_admitted
 
-cfg = get_config("granite-3-8b", smoke=True)
-params = init_params(cfg, jax.random.PRNGKey(0))
-rng = np.random.default_rng(5)
-def reqs():
-    return [Request(uid=i, prompt=p.copy(), max_new_tokens=5,
-                    arrival_time=0.005 * i)
-            for i, p in enumerate(prompts)]
-prompts = [rng.integers(2, cfg.vocab_size, L).astype(np.int32)
-           for L in (3, 9, 12, 5)]
-out = {}
-for threaded in (False, True):
-    eng = ServingEngine(params, cfg, num_slots=2, cache_len=32,
-                        prefill_buckets=(8, 16), cache_layout="paged",
-                        block_size=8)
-    done = eng.run_threaded(reqs()) if threaded else eng.run(reqs())
-    out[threaded] = {r.uid: list(r.generated) for r in done}
-assert len(out[False]) == 4 and out[True] == out[False], out
-print("THREADED_BITWISE_OK")
-"""
+
+def test_kernel_registry_does_not_import_the_server():
+    """The kernel registry is the lower layer: importing it registers every
+    kernel and leaves no ``repro.serving`` module loaded."""
+    code = ("import sys, repro.kernels\n"
+            "print(sorted(m for m in sys.modules\n"
+            "             if m.split('.')[:2] == ['repro', 'serving']))\n")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [src] + [p for p in [os.environ.get("PYTHONPATH")] if p]))
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                          text=True, timeout=600)
+                          text=True, timeout=300, env=env)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "THREADED_BITWISE_OK" in proc.stdout
+    assert proc.stdout.strip() == "[]", proc.stdout
 
 
 def test_exact_fit_prompt(params):
@@ -350,14 +358,13 @@ def test_eos_on_prefill_token(params):
     req = _requests([5], max_new=8)[0]
     first = _oracle_tokens(params, req, 32)[0]
     eng = ServingEngine(params, CFG, num_slots=2, cache_len=32,
-                        prefill_len=8, cache_layout="paged", block_size=8)
+                        prefill_len=8)
     req = _requests([5], max_new=8)[0]
     req.eos_id = first
     done = eng.run([req])
     assert len(done) == 1 and done[0].generated == [first]
     assert eng.stats["decode_steps"] == 0
     assert eng.slots.available() == 2
-    assert eng.balloc.available() == eng.balloc.capacity()
 
 
 def test_finish_and_admit_same_step(params):
@@ -452,26 +459,3 @@ def test_latency_summary_counts_unfinished():
     assert lat["unfinished"] == 2
     empty = latency_summary(synthetic_trace(3, vocab_size=64, seed=4))
     assert empty == {"requests": 0, "submitted": 3, "unfinished": 3}
-
-
-def test_block_allocator_cycle():
-    ba = BlockAllocator(num_blocks=RESERVED_BLOCKS + 4, block_size=8)
-    assert ba.capacity() == 4 and ba.available() == 4
-    # positions written: prompt_len + max_new - 1 (last token never cached)
-    assert ba.blocks_for(1, 1) == 1      # 1 position -> 1 page
-    assert ba.blocks_for(8, 1) == 1      # 8 positions, exact fit
-    assert ba.blocks_for(8, 2) == 2      # 9 positions spill a page
-    assert ba.blocks_for(3, 6) == 1
-    a = ba.alloc(2)
-    assert a == [RESERVED_BLOCKS, RESERVED_BLOCKS + 1]   # dense, low first
-    assert ba.available() == 2 and ba.in_use() == 2
-    with pytest.raises(RuntimeError):
-        ba.alloc(3)                      # pool exhausted
-    ba.free(a)
-    assert ba.available() == 4
-    with pytest.raises(ValueError):
-        ba.free([a[0]])                  # double free
-    with pytest.raises(ValueError):
-        ba.free([0])                     # reserved sentinel page
-    with pytest.raises(ValueError):
-        BlockAllocator(num_blocks=RESERVED_BLOCKS, block_size=8)

@@ -55,16 +55,6 @@ def test_registry_cell_audits_clean(kernel, backend):
         assert s.reason  # a skip always says why
 
 
-def test_non_jaxpr_traceable_kernels_excluded_from_audit():
-    """Host-side driver-loop kernels (serving.engine) stay in conformance
-    but have no jaxpr for the static passes — the audit matrix skips them."""
-    from repro.core import conformance
-    assert registry.get("serving.engine").jaxpr_traceable is False
-    assert any(k == "serving.engine"
-               for k, _ in conformance.conformance_pairs())
-    assert not any(k == "serving.engine" for k, _ in analysis.audit_pairs())
-
-
 def test_audit_matrix_derives_from_live_registry():
     """Registering a backend adds its audit cell with no suite edit."""
     k = registry.get("stencil7")

@@ -6,7 +6,6 @@ on a trace emitted by a real engine run."""
 
 import gc
 import glob
-import json
 import os
 import pathlib
 import subprocess
@@ -433,59 +432,3 @@ def test_engine_programs_keep_the_names_the_mfu_readers_read(params):
         assert lowered.as_text().startswith(f"module @{module} "), module
         text = (root / "bench" / "metrics" / f"{reader}.py").read_text()
         assert f'"{module}"' in text, reader
-
-
-# --------------------------------------------------------------------------
-# serving benchmark v4 drift check (slow lane; the --smoke CLI also covers)
-# --------------------------------------------------------------------------
-@pytest.mark.slow
-def test_serving_benchmark_smoke_writes_v4_artifact(tmp_path, monkeypatch):
-    from benchmarks import serving as bench
-
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "tuning.json"))
-    json_path = str(tmp_path / "BENCH_serving.json")
-    artifact = bench.run(smoke=True, json_path=json_path)
-    on_disk = json.loads((tmp_path / "BENCH_serving.json").read_text())
-
-    assert on_disk["schema"] == "repro.serving/v4"
-    assert on_disk["jax_compile_events"] > 0      # the recompile counter
-    assert on_disk["telemetry"]["counters"]
-    # the sweep: 2 backends x both cache layouts x a >=3-point rate ladder
-    assert len(on_disk["rates_rps"]) >= 3
-    backends = sorted({r["backend"] for r in on_disk["rows"]})
-    assert len(backends) == 2 and "xla" in backends
-    assert ({r["cache_layout"] for r in on_disk["rows"]}
-            == {"contiguous", "paged"})
-    cells = {(r["backend"], r["cache_layout"], r["rate_rps"])
-             for r in on_disk["rows"]}
-    assert len(cells) == 2 * 2 * len(on_disk["rates_rps"])
-    for row in on_disk["rows"]:
-        assert not row["retraced"]
-        # a row whose trace didn't drain would have raised inside run();
-        # the artifact still records the accounting
-        assert row["unfinished"] == 0
-        assert row["submitted"] == row["requests"]
-        for col in ("ttft_p99_ms", "latency_p99_ms", "itl_p50_ms",
-                    "itl_p95_ms", "itl_p99_ms", "jax_compile_events"):
-            assert row[col] is not None and row[col] >= 0, col
-        # warmup walked the whole bucket ladder: timed runs never compile
-        assert row["telemetry"]["jax_compile_events_timed"] == 0
-        assert row["telemetry"]["spans"]["serving.decode_step"]["count"] > 0
-    # bounded-compile contract per engine: one prefill program per ladder
-    # rung at most, exactly one decode program
-    assert len(on_disk["engines"]) == 4
-    for e in on_disk["engines"]:
-        assert e["prefill_traces"] <= len(on_disk["prefill_buckets"])
-        assert e["decode_traces"] == 1
-    # the pallas rows must dispatch through the registry, not fall back
-    for row in on_disk["rows"]:
-        if row["backend"] != "xla":
-            assert row["dispatch"]["decode"]["backend"] != "xla"
-
-    # trace artifact: the JSONL log summarizes
-    summary = tel.summarize_file(artifact["trace_jsonl"])
-    assert summary["spans"]["serving.decode_step"]["count"] > 0
-    assert summary["counters"][jaxmon.COMPILE_COUNTER] == \
-        on_disk["jax_compile_events"]
-    # telemetry was owned by the benchmark and is off again
-    assert not tel.enabled()

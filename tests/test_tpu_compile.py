@@ -114,15 +114,16 @@ def test_decode_granite_four_slots(one_chip):
               ((SLOTS, CACHE_LEN), I32)])
 
 
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
-@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("program", ["prefill-128", "prefill-1536",
+                                     "decode"])
 def test_engine_programs_update_the_cache_in_place(one_chip, monkeypatch,
-                                                   program, layout):
+                                                   program):
     """The serving engine's own programs at the granite cell's slots and
-    cache length (two layers: the copy shows at any depth) take the KV
-    cache over: the whole cache is aliased to the output, and no K or V
-    leaf is copied whole into a fresh buffer."""
-    slots, cache_len, bucket = 24, 2048, 128
+    cache length (two layers: the copy shows at any depth), prefill at the
+    cell's smallest and largest bucket, take the KV cache over: the whole
+    cache is aliased to the output, and no K or V leaf is copied whole into
+    a fresh buffer."""
+    slots, cache_len, buckets = 24, 2048, (128, 1536)
     cfg = dataclasses.replace(get_config("granite-3-8b"), n_layers=2,
                               param_dtype="bfloat16")
 
@@ -135,24 +136,20 @@ def test_engine_programs_update_the_cache_in_place(one_chip, monkeypatch,
 
     params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
     with monkeypatch.context() as m:       # the engine's cache, shapes only
-        for name in ("init_caches", "init_paged_caches"):
-            made = getattr(E, name)
-            m.setattr(E, name, lambda *a, made=made, **k:
-                      jax.eval_shape(lambda: made(*a, **k)))
+        made = E.init_caches
+        m.setattr(E, "init_caches", lambda *a, **k:
+                  jax.eval_shape(lambda: made(*a, **k)))
         eng = E.ServingEngine(params, cfg, num_slots=slots,
-                              cache_len=cache_len, prefill_len=bucket,
-                              cache_layout=layout)
+                              cache_len=cache_len, prefill_buckets=buckets)
     caches = on_chip(eng.caches)
-    table = ([arg((slots, eng.pages_per_slot), I32)]
-             if layout == "paged" else [])
-    if program == "prefill":
-        row = [arg((eng.pages_per_slot,), I32)] if table else []
+    if program.startswith("prefill"):
+        bucket = int(program.split("-")[1])
         args = [on_chip(params), arg((1, bucket), I32), arg((1,), I32),
-                *row, arg((), I32), arg((2,), jnp.uint32), caches]
+                arg((), I32), arg((2,), jnp.uint32), caches]
         fn = eng._prefill
     else:
         args = [on_chip(params), arg((slots, 1), I32), arg((slots, 1), I32),
-                arg((slots, 2), jnp.uint32), caches, *table]
+                arg((slots, 2), jnp.uint32), caches]
         fn = eng._decode
     compiled = fn.lower(*args).compile()
 
