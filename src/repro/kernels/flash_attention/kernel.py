@@ -17,7 +17,12 @@ Two kernel shapes share the online-softmax machinery:
   reaches, so position mode visits them all and lets the mask decide).
 
 * ``decode_attention`` — single-query serving decode against a ring-buffer
-  KV cache, grid (B, Kv, n_t) with the cache axis innermost/sequential.
+  KV cache, read in the layout the cache stores it, (B, T, Kv, Dh): grid
+  (B, T // bkv) with the cache axis innermost/sequential, each step one
+  (1, bkv, Kv, Dh) block of K and of V — every KV head of ``bkv`` slots,
+  the full last two dims, so no transposed copy is made — and a static
+  loop over the Kv heads inside.  ``bkv`` comes from the shape
+  (``decode_block``: the largest choice whose K block fits about 1 MB).
   The cache carries the absolute position of every slot (-1 = empty), so
   wraparound needs no special handling: masking is purely position-based
   (``kp >= 0 & kp <= qp`` + optional sliding window) and slot order never
@@ -27,8 +32,8 @@ Two kernel shapes share the online-softmax machinery:
   where the XLA oracle returns a uniform average of v, both garbage by
   contract.
 
-BlockSpec dims are (bq x dh) / (bk x dh) MXU-aligned tiles; softmax state
-accumulates in fp32.
+Prefill BlockSpec dims are (bq x dh) / (bk x dh) MXU-aligned tiles; softmax
+state accumulates in fp32 in both kernels, K and V upcast to fp32 in VMEM.
 """
 
 from __future__ import annotations
@@ -44,7 +49,11 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
-DEFAULT_BKV = 256
+#: decode cache-axis block sizes; the shape picks one (``decode_block``)
+BKV_CHOICES = (64, 128, 256, 512)
+#: VMEM budget of one decode K block (V takes as much again, and each is
+#: double-buffered)
+DECODE_BLOCK_BYTES = 1 << 20
 
 
 def _flash_body(*refs, bq: int, bk: int, n_k: int, causal: bool, window: int,
@@ -173,9 +182,27 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal: bool = True,
 # --------------------------------------------------------------------------
 # decode: single query against the ring-buffer KV cache
 # --------------------------------------------------------------------------
+def decode_block(t: int, kv: int, dh: int, itemsize: int):
+    """Cache-axis block ``bkv`` for a decode call, from its shape alone.
+
+    The largest of ``BKV_CHOICES`` that ``t`` admits (divides it, or
+    covers it whole: then the block is ``t``) and whose K block
+    ``bkv * kv * dh * itemsize`` stays within ``DECODE_BLOCK_BYTES``; the
+    smallest admissible choice when none fits.  None when no choice
+    admits ``t``.
+    """
+    ok = [c for c in BKV_CHOICES if t % c == 0 or t <= c]
+    if not ok:
+        return None
+    fits = [c for c in ok if min(c, t) * kv * dh * itemsize
+            <= DECODE_BLOCK_BYTES]
+    return min(max(fits or ok[:1]), t)
+
+
 def _decode_body(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, m_scr, l_scr,
-                 acc_scr, *, n_t: int, window: int, scale: float):
-    ti = pl.program_id(2)
+                 acc_scr, *, n_t: int, kv: int, g: int, window: int,
+                 scale: float):
+    ti = pl.program_id(1)
 
     @pl.when(ti == 0)
     def _init():
@@ -183,74 +210,89 @@ def _decode_body(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (G, dh)
-    k = k_ref[0, 0].astype(jnp.float32)              # (bkv, dh)
-    v = v_ref[0, 0].astype(jnp.float32)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (G, bkv)
-
     qp = qp_ref[pl.program_id(0)]                    # scalar int32 (SMEM)
     kp = kp_ref[0]                                   # (1, bkv) int32
     mask = (kp >= 0) & (kp <= qp)                    # empty slots + causal
     if window:
         mask = mask & ((qp - kp) < window)
-    logits = jnp.where(jnp.broadcast_to(mask, logits.shape), logits, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    for h in range(kv):                              # static: Kv heads
+        rows = slice(h * g, (h + 1) * g)             # this head's G queries
+        q = q_ref[0, rows, :].astype(jnp.float32)    # (G, dh)
+        k = k_ref[0, :, h, :].astype(jnp.float32)    # (bkv, dh)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (G, bkv)
+        logits = jnp.where(jnp.broadcast_to(mask, logits.shape), logits,
+                           NEG_INF)
+
+        m_prev = m_scr[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)
+        l_scr[rows, :] = l_scr[rows, :] * corr + jnp.sum(p, axis=1,
+                                                         keepdims=True)
+        acc_scr[rows, :] = acc_scr[rows, :] * corr + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[rows, :] = m_new
 
     @pl.when(ti == n_t - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...]
+                    / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
-                     bkv: int = DEFAULT_BKV, interpret: bool = False):
-    """q (B,Kv,G,Dh), k/v (B,Kv,T,Dh), q_pos (B,1), k_pos (B,1,T) int32
-    -> (B,Kv,G,Dh).
+                     bkv: int | None = None, interpret: bool = False):
+    """q (B,H,Dh), k/v (B,T,Kv,Dh), q_pos (B,) / k_pos (B,1,T) int32
+    -> (B,H,Dh).
+
+    K and V are read as the cache stores them: one grid step takes a
+    ``(1, bkv, Kv, Dh)`` block of each, every KV head of ``bkv`` slots,
+    and loops over the heads; query rows ``h*G .. (h+1)*G`` attend KV
+    head ``h``.  Grid ``(B, T // bkv)``, the cache axis innermost and
+    sequential.  ``bkv`` defaults to :func:`decode_block` of the shape.
 
     One query token per row against a position-annotated KV cache: slot
     order is arbitrary (ring buffers arrive as stored), ``k_pos == -1``
     marks empty slots, and ``window > 0`` additionally restricts to
-    ``q_pos - k_pos < window``.  ``bkv`` tiles the cache axis.
+    ``q_pos - k_pos < window``.
     """
-    b, kv, g, dh = q.shape
-    t = k.shape[2]
+    b, h, dh = q.shape
+    t, kv = k.shape[1], k.shape[2]
+    g = h // kv
+    if bkv is None:
+        bkv = decode_block(t, kv, dh, k.dtype.itemsize)
+        if bkv is None:
+            raise ValueError(f"no block size in {BKV_CHOICES} admits "
+                             f"cache length T={t}")
     bkv = min(bkv, t)
     if t % bkv:
         raise ValueError(f"cache length T={t} must divide block size {bkv}")
     n_t = t // bkv
     scale = 1.0 / math.sqrt(dh)
 
-    body = functools.partial(_decode_body, n_t=n_t, window=window,
-                             scale=scale)
+    body = functools.partial(_decode_body, n_t=n_t, kv=kv, g=g,
+                             window=window, scale=scale)
     return pl.pallas_call(
         body,
-        grid=(b, kv, n_t),
+        grid=(b, n_t),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda b_, k_, ti: (b_, k_, 0, 0)),
-            pl.BlockSpec((1, 1, bkv, dh), lambda b_, k_, ti: (b_, k_, ti, 0)),
-            pl.BlockSpec((1, 1, bkv, dh), lambda b_, k_, ti: (b_, k_, ti, 0)),
+            pl.BlockSpec((1, h, dh), lambda b_, ti: (b_, 0, 0)),
+            pl.BlockSpec((1, bkv, kv, dh), lambda b_, ti: (b_, ti, 0, 0)),
+            pl.BlockSpec((1, bkv, kv, dh), lambda b_, ti: (b_, ti, 0, 0)),
             # every row's query position, whole in SMEM: a (1, 1) block of
             # a (B, 1) array breaks the TPU (8, 128) tiling rule for B > 1
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, bkv), lambda b_, k_, ti: (b_, 0, ti)),
+            pl.BlockSpec((1, 1, bkv), lambda b_, ti: (b_, 0, ti)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda b_, k_, ti: (b_, k_, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, dh), lambda b_, ti: (b_, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),     # running max m
-            pltpu.VMEM((g, 1), jnp.float32),     # running denom l
-            pltpu.VMEM((g, dh), jnp.float32),    # running accumulator
+            pltpu.VMEM((h, 1), jnp.float32),     # running max m
+            pltpu.VMEM((h, 1), jnp.float32),     # running denom l
+            pltpu.VMEM((h, dh), jnp.float32),    # running accumulator
         ],
         interpret=interpret,
-    )(q, k, v, q_pos.reshape(b), k_pos)
+    )(q, k, v, q_pos, k_pos)

@@ -9,7 +9,12 @@ Registers two kernel families the serving hot path dispatches through
   * ``attention.decode`` — single-query ring-buffer decode, *model-native*
     layout q (B,1,H,Dh) / k,v (B,T,Kv,Dh) / q_pos (B,1) / k_pos (B,T), so
     the ``xla`` oracle is literally the plain-XLA ``attend`` path serving
-    has always run (bitwise, no layout moves).
+    has always run (bitwise, no layout moves).  The Pallas kernel reads K
+    and V in that stored layout too: grid (B, T // bkv), one
+    (1, bkv, Kv, Dh) block of each per step holding every KV head; only q
+    and the output are reshaped, (B,1,H,Dh) <-> (B,H,Dh), which is free.
+    ``bkv`` is the tunable; left unset it is chosen from the shape
+    (``kernel.decode_block``), 512 at granite-3-8b's Kv=8, Dh=128 in bf16.
 
 The compiled ``pallas`` backend declares ``available=on_tpu`` and always
 compiles (``interpret=False``); interpret mode is reached only through the
@@ -47,19 +52,14 @@ flash_xla = jax.jit(flash_ref, static_argnames=("causal", "window"))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bkv", "interpret"))
-def decode_pallas(q, k, v, q_pos, k_pos, *, window=0, bkv=K.DEFAULT_BKV,
+def decode_pallas(q, k, v, q_pos, k_pos, *, window=0, bkv=None,
                   interpret=False):
     b, s, h, dh = q.shape          # s == 1 (single decode query)
-    kv = k.shape[2]
-    g = h // kv
-    qk = q.reshape(b, h, dh).reshape(b, kv, g, dh)     # kv-major head order
-    kk = jnp.moveaxis(k, 1, 2)                         # (B,Kv,T,Dh)
-    vk = jnp.moveaxis(v, 1, 2)
     out = K.decode_attention(
-        qk, kk, vk, q_pos.astype(jnp.int32),
+        q.reshape(b, h, dh), k, v, q_pos.astype(jnp.int32).reshape(b),
         k_pos.astype(jnp.int32)[:, None, :], window=window, bkv=bkv,
         interpret=interpret)
-    return out.reshape(b, h, dh).reshape(b, s, h, dh)
+    return out.reshape(b, s, h, dh)
 
 
 decode_xla = jax.jit(decode_ref, static_argnames=("window",))
@@ -107,7 +107,7 @@ _kd.add_backend("pallas_interpret",
 # cache-axis block size of the online-softmax loop — must divide cache_len
 _kd.declare_tunables(
     ("pallas", "pallas_interpret"),
-    bkv=(64, 128, 256, 512),
+    bkv=K.BKV_CHOICES,
     constraint=lambda p, q, k, v, *a, **kw:
         k.shape[1] % p["bkv"] == 0 or k.shape[1] <= p["bkv"])
 # same online-softmax accumulator shape along the cache-axis grid

@@ -268,15 +268,17 @@ def attend(q, k, v, q_pos, k_pos, *, n_kv_heads: int, causal: bool,
         if kind == "decode":
             params, prov = _tuned_params(kernel, q, k, v, q_pos, k_pos,
                                          backend=resolved, window=window)
-            bkv = min(params.get("bkv", 256), t)
-            if t % bkv == 0:
+            from repro.kernels.flash_attention.kernel import decode_block
+            bkv = params.get("bkv") or decode_block(
+                t, k.shape[2], k.shape[3], k.dtype.itemsize)
+            if bkv is not None and t % min(bkv, t) == 0:
                 _log(kind, backend=resolved, kernel=kernel.name,
                      tuning=prov, params=params)
                 return kernel(q, k, v, q_pos, k_pos, backend=resolved,
                               window=window, **params)
             _log(kind, backend="xla", kernel=kernel.name, tuning="n/a",
                  params={}, fallback=f"cache_len {t} not divisible by "
-                                     f"block {bkv}")
+                                     f"a decode block size")
         else:
             qk = jnp.moveaxis(q, 2, 1)           # (B,H,S,Dh) kernel layout
             kk = jnp.moveaxis(k, 2, 1)           # (B,Kv,T,Dh)

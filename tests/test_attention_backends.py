@@ -121,6 +121,32 @@ def test_decode_interpret_leftpad_holes():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(got2))
 
 
+@pytest.mark.parametrize("bkv", [64, 128], ids=["bkv<T", "bkv=T"])
+@pytest.mark.parametrize("window", [0, 16])
+@pytest.mark.parametrize("dh", [64, 128])
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("kv", [1, 2, 8])
+def test_decode_interpret_stored_layout_vs_xla(kv, g, dh, window, bkv):
+    """The kernel reads the cache as stored, all KV heads a grid step:
+    every head count, group size and head width, with row 0's ring
+    wrapped and row 1 partly empty (-1 holes)."""
+    k = registry.get("attention.decode")
+    t = 128
+    q, kc, vc, _, _ = _decode_inputs(30 + kv + g + dh, h=kv * g, kv=kv,
+                                     t=t, dh=dh)
+    pos = np.tile(np.arange(t, dtype=np.int32), (2, 1))
+    pos[0, :5] += t                 # row 0: ring wrapped at slots 0..4
+    pos[1, 41:] = -1                # row 1: cache only 41/128 full
+    qp = jnp.asarray([[t + 5], [41]], jnp.int32)
+    kp = jnp.asarray(pos)
+    want = k(q, kc, vc, qp, kp, backend="xla", window=window)
+    got = k(q, kc, vc, qp, kp, backend="pallas_interpret", window=window,
+            bkv=bkv)
+    rtol, atol = conformance.ORACLE_TOL["attention.decode"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=rtol, atol=atol)
+
+
 def test_decode_cache_len_one():
     k = registry.get("attention.decode")
     q, kc, vc, qp, kp = _decode_inputs(5, t=1, q_pos=[0, 0])
@@ -210,8 +236,19 @@ def test_attend_dispatch_routes_and_falls_back(monkeypatch):
     assert A.dispatch_log()["decode"]["backend"] == "xla"
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-    # a cache length no block size divides falls back to XLA with a reason
+    # a cache length below the largest block is one block of its own
     q2, kc2, vc2, qp2, kp2 = _decode_inputs(8, t=300)
+    A.reset_dispatch_log()
+    got2 = A.attend(q2, kc2, vc2, qp2, kp2, n_kv_heads=2, causal=True,
+                    backend="pallas_interpret")
+    assert A.dispatch_log()["decode"]["backend"] == "pallas_interpret"
+    np.testing.assert_allclose(
+        np.asarray(got2), np.asarray(_ref(q2, kc2, vc2, qp2, kp2, kv=2)),
+        rtol=RTOL, atol=ATOL)
+
+    # a longer cache length no block size divides falls back to XLA with a
+    # reason
+    q2, kc2, vc2, qp2, kp2 = _decode_inputs(8, t=600)
     A.reset_dispatch_log()
     got2 = A.attend(q2, kc2, vc2, qp2, kp2, n_kv_heads=2, causal=True,
                     backend="pallas_interpret")

@@ -114,6 +114,26 @@ def test_decode_granite_four_slots(one_chip):
               ((SLOTS, CACHE_LEN), I32)])
 
 
+def test_decode_reads_the_cache_as_stored(one_chip):
+    """At the serving cell's 24 slots x 2048 positions the decode kernel
+    takes K and V as the cache stores them: one Mosaic kernel, and no
+    instruction but the parameters makes a whole K or V, neither
+    transposed (B, Kv, T, Dh) nor copied (B, T, Kv, Dh)."""
+    slots, cache_len = 24, 2048
+    text = _compile(one_chip, fa_ops.decode_pallas,
+                    [((slots, 1, H, DH), BF16),
+                     ((slots, cache_len, KV, DH), BF16),
+                     ((slots, cache_len, KV, DH), BF16), ((slots, 1), I32),
+                     ((slots, cache_len), I32)])
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    wholes = [f"bf16[{slots},{KV},{cache_len},{DH}]",
+              f"bf16[{slots},{cache_len},{KV},{DH}]"]
+    made = [ln for ln in text.splitlines()
+            if any(f"= {w}" in ln or f"= ({w}" in ln for w in wholes)
+            and " parameter(" not in ln]
+    assert not made, made
+
+
 @pytest.mark.parametrize("program", ["prefill-128", "prefill-1536",
                                      "decode"])
 def test_engine_programs_update_the_cache_in_place(one_chip, monkeypatch,
